@@ -25,7 +25,7 @@ def _random_tasks(n, rng):
                     for i in range(n)])
 
 
-def _fleet(n, rng):
+def array_fleet(n, rng):
     """Array-built fleet (single-task jobs): the (W, F, R) profile matrix is
     gathered per task, so construction stays O(n) with no Python loop."""
     prof = np.zeros((NUM_WORKLOADS, len(FAMILIES), NUM_RESOURCES))
@@ -159,7 +159,7 @@ def scaling_curve(sizes=(1000, 10_000, 100_000, 1_000_000), quick=False):
 def _scaling_rows(sizes, cat, kw, prof):
     rows = []
     for n in sizes:
-        tasks = _fleet(n, np.random.default_rng(n))
+        tasks = array_fleet(n, np.random.default_rng(n))
         dt_np = None
         if n <= NUMPY_CAP:
             t0 = time.time()

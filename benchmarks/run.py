@@ -42,7 +42,11 @@ def main():
                     help="write the run report (per-bench timings) as JSON")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.obs import Reporter
+
+    use_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     from . import (bench_arrival, bench_autoscale, bench_composition,
                    bench_credits, bench_endtoend, bench_interference,

@@ -101,7 +101,8 @@ def _collapse_classes(workloads: np.ndarray, rp: np.ndarray, jr: np.ndarray,
     else:  # per-workload keys vary (e.g. per-job RP sums): full row unique
         full = cols if merge_workloads else np.column_stack(
             [workloads.astype(np.float64), cols])
-        _, uidx, inv = np.unique(full, axis=0, return_inverse=True)
+        _, uidx, inv = np.unique(full, axis=0, return_index=True,
+                                 return_inverse=True)
         inv = inv.reshape(-1)
         cw = workloads[uidx].astype(np.int64)
         keys = cols[uidx]
@@ -143,20 +144,26 @@ def _pack_all_types(cdemand, cw, crp, cjr, counts0, rows_pad, P, logP,
     # instance cost under the catalog's linear pricing, so the gate needs a
     # tolerance matched to the accumulator dtype — f32 greedy sums drift
     # ~n·eps·cost over an n-task fill; under jax_enable_x64 the relative
-    # term collapses below the absolute 1e-9 epsilon, matching numpy
+    # term collapses below the absolute 1e-9 epsilon, matching numpy.  The
+    # fit test gets the same slack on the remaining capacity: decimal
+    # demands (e.g. 173.6 GB) leave f32 residuals below an exact fit
     rtol = dt.type(256 * jnp.finfo(dt).eps)
 
     def fill_one(counts, d, cap0):
         """Greedy-fill one fresh instance; returns (used, tnrp, had_tie)."""
+        fit_tol = _EPS + rtol * cap0
+
         def cond(s):
             return ~s[-1]
 
         def body(s):
             used, capr, logtput, agg, cur, tie, _ = s
             feas = ((counts - used) > 0) & jnp.all(
-                d <= capr[None, :] + _EPS, axis=1)
+                d <= capr[None, :] + fit_tol[None, :], axis=1)
             cand_tput = jnp.exp(logtput)
-            qvec = agg @ Q
+            # full f32 passes: at default precision the TPU's MXU rounds
+            # through bf16, which can flip the argmax under interference
+            qvec = jnp.matmul(agg, Q, precision=jax.lax.Precision.HIGHEST)
             score = cur - qvec[cw] + crp - (1.0 - cand_tput) * cjr
             masked = jnp.where(feas, score, dt.type(_NEG))
             mx = masked.max()

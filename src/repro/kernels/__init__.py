@@ -2,15 +2,3 @@
 #   kernel.py — pl.pallas_call + explicit BlockSpec VMEM tiling
 #   ops.py    — jit'd wrapper dispatching pallas (TPU) vs reference (CPU)
 #   ref.py    — pure-jnp oracle used by tests and the CPU dry-run
-
-from jax.experimental.pallas import tpu as _pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Compat shim: pltpu.TPUCompilerParams (jax <= 0.4.x) was renamed to
-    pltpu.CompilerParams (jax >= 0.5); accept either so the kernels run on
-    both toolchains."""
-    cls = getattr(_pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = _pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -5,6 +5,10 @@ innermost and sequential; online-softmax statistics (m, l) and the output
 accumulator live in VMEM scratch across KV iterations.  Blocks are
 MXU-aligned (block_q = block_k = 128 by default).  Causal/local block
 skipping prunes fully-masked KV blocks via pl.when.
+
+The kernel is forward-only; ``flash_attention_pallas`` carries a custom VJP
+whose backward recomputes through the memory-efficient jnp oracle
+(``ref.attention_chunked``), so training steps differentiate through it.
 """
 from __future__ import annotations
 
@@ -16,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
+from .ref import attention_chunked
 
 NEG_INF = -1e30
 
@@ -80,6 +84,10 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """q: (B, Sq, H, hd); k/v: (B, Sk, KH, hd).  Sq == Sk (self-attention
     train/prefill); decode-style single-token attention should use the
     reference matvec path instead."""
+    return _flash(q, k, v, causal, window, block_q, block_k, interpret)
+
+
+def _flash_forward(q, k, v, causal, window, block_q, block_k, interpret):
     B, Sq, H, hd = q.shape
     _, Sk, KH, _ = k.shape
     assert H % KH == 0
@@ -117,8 +125,22 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((block_q,), jnp.float32),      # l: running denom
             pltpu.VMEM((block_q, hd), jnp.float32),   # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qh, kh, vh)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
+
+
+def _flash_fwd(q, k, v, *static):
+    return _flash_forward(q, k, v, *static), (q, k, v)
+
+
+def _flash_bwd(causal, window, block_q, block_k, interpret, res, g):
+    _, vjp = jax.vjp(functools.partial(attention_chunked, causal=causal,
+                                       window=window), *res)
+    return vjp(g)
+
+
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(3, 4, 5, 6, 7))
+_flash.defvjp(_flash_fwd, _flash_bwd)

@@ -20,47 +20,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import tpu_compiler_params
 
-
-def _kernel(x_ref, dt_ref, cum_ref, b_ref, c_ref, y_ref, cin_ref, *,
+def _kernel(x_ref, row_ref, col_ref, b_ref, c_ref, y_ref, cin_ref, *,
             chunk: int):
-    x = x_ref[0].astype(jnp.float32)      # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)    # (Q,)
-    cum = cum_ref[0].astype(jnp.float32)  # (Q,)
-    Bm = b_ref[0].astype(jnp.float32)     # (Q, N)
-    Cm = c_ref[0].astype(jnp.float32)     # (Q, N)
+    # dt and cum arrive twice, as rows (2, Q) and as columns (Q, 2), so the
+    # outer difference and both broadcasts need no in-kernel transpose
+    x = x_ref[0].astype(jnp.float32)        # (Q, P)
+    dt_r = row_ref[0, 0:1, :]               # (1, Q)
+    cum_r = row_ref[0, 1:2, :]              # (1, Q)
+    dt_c = col_ref[0, :, 0:1]               # (Q, 1)
+    cum_c = col_ref[0, :, 1:2]              # (Q, 1)
+    Bm = b_ref[0].astype(jnp.float32)       # (Q, N)
+    Cm = c_ref[0].astype(jnp.float32)       # (Q, N)
 
-    diff = cum[:, None] - cum[None, :]
+    diff = cum_c - cum_r
     qi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     L = jnp.exp(jnp.where(qi >= ki, diff, -jnp.inf))  # mask pre-exp
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ()))) * L
-    scores = scores * dt[None, :]
+    scores = scores * dt_r
     y_ref[0] = jax.lax.dot(scores, x).astype(y_ref.dtype)
 
-    decay_end = jnp.exp(cum[-1] - cum)  # (Q,)
-    xw = x * (dt * decay_end)[:, None]  # (Q, P)
+    decay_end = jnp.exp(cum_c[chunk - 1:, :] - cum_c)  # (Q, 1)
+    xw = x * (dt_c * decay_end)  # (Q, P)
     cin_ref[0, 0] = jax.lax.dot_general(
         xw, Bm, (((0,), (0,)), ((), ()))).astype(cin_ref.dtype)  # (P, N)
 
 
 def ssd_chunk_pallas(x, dt, cum, Bm, Cm, *, chunk: int,
                      interpret: bool = False):
-    """x: (BH, S, P); dt/cum: (BH, S); Bm/Cm: (BH, S, N) (already
+    """x: (BH, S, P); dt/cum: (BH, S) float32; Bm/Cm: (BH, S, N) (already
     head-expanded).  Returns (y_intra (BH,S,P), chunk_in (BH,nc,P,N))."""
     BH, S, P = x.shape
     N = Bm.shape[-1]
     assert S % chunk == 0
     nc = S // chunk
+    rows = jnp.stack([dt, cum], axis=1)  # (BH, 2, S)
+    cols = jnp.stack([dt, cum], axis=2)  # (BH, S, 2)
     kernel = functools.partial(_kernel, chunk=chunk)
     y, cin = pl.pallas_call(
         kernel,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, ci: (bh, ci)),
-            pl.BlockSpec((1, chunk), lambda bh, ci: (bh, ci)),
+            pl.BlockSpec((1, 2, chunk), lambda bh, ci: (bh, 0, ci)),
+            pl.BlockSpec((1, chunk, 2), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda bh, ci: (bh, ci, 0)),
         ],
@@ -72,8 +76,8 @@ def ssd_chunk_pallas(x, dt, cum, Bm, Cm, *, chunk: int,
             jax.ShapeDtypeStruct((BH, S, P), jnp.float32),
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(x, dt, cum, Bm, Cm)
+    )(x, rows, cols, Bm, Cm)
     return y, cin

@@ -1,5 +1,6 @@
 """Jit'd SSD entry point: Pallas intra-chunk kernel + jnp state passing on
-TPU, chunked pure-jnp implementation elsewhere."""
+TPU, chunked pure-jnp implementation elsewhere.  The Pallas path carries a
+custom VJP whose backward recomputes through ``ssd_chunked_ref``."""
 from __future__ import annotations
 
 import jax
@@ -32,7 +33,10 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 256, h0=None, impl: str = "auto",
         return ssd_chunked_ref(x, dt, A, B, C, D, chunk=chunk, h0=h0)
     if impl == "sequential":
         return ssd_ref(x, dt, A, B, C, D, h0=h0)
+    return _ssd_pallas(x, dt, A, B, C, D, h0, chunk, interpret)
 
+
+def _ssd_pallas_forward(x, dt, A, B, C, D, h0, chunk, interpret):
     Bt, S, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     rep = H // G
@@ -73,3 +77,20 @@ def ssd(x, dt, A, B, C, D, *, chunk: int = 256, h0=None, impl: str = "auto",
     y = y.reshape(Bt, H, S, P).transpose(0, 2, 1, 3)
     y = y + x.astype(jnp.float32) * D[None, None, :, None]
     return y.astype(x.dtype), h_final.reshape(Bt, H, P, N)
+
+
+def _ssd_fwd(x, dt, A, B, C, D, h0, *static):
+    return _ssd_pallas_forward(x, dt, A, B, C, D, h0, *static), \
+        (x, dt, A, B, C, D, h0)
+
+
+def _ssd_bwd(chunk, interpret, res, g):
+    def ref(x, dt, A, B, C, D, h0):
+        return ssd_chunked_ref(x, dt, A, B, C, D, chunk=chunk, h0=h0)
+
+    _, vjp = jax.vjp(ref, *res)
+    return vjp(g)
+
+
+_ssd_pallas = jax.custom_vjp(_ssd_pallas_forward, nondiff_argnums=(7, 8))
+_ssd_pallas.defvjp(_ssd_fwd, _ssd_bwd)

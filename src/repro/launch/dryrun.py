@@ -31,7 +31,7 @@ from ..models import lm  # noqa: E402
 from ..models.sharding import mesh_context  # noqa: E402
 from ..models.steps import (make_decode_step, make_prefill_step,  # noqa: E402
                             make_train_step)
-from .mesh import make_production_mesh  # noqa: E402
+from .mesh import make_mesh, make_production_mesh  # noqa: E402
 from .specs import input_specs  # noqa: E402
 
 # TPU v5e hardware constants (per chip)
@@ -154,7 +154,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
              extract_roofline: bool = True, profile: str = "2d",
              mesh_shape=None):
     if mesh_shape is not None:  # logical re-mesh of the same 256-chip pod
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = int(np.prod(list(mesh.shape.values())))

@@ -7,6 +7,15 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """Mesh whose axes are all ``Auto``: shardings propagate through XLA
+    from ``constrain`` hints (``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which those hints and the embedding gather are refused)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +23,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis (pure DP over the slower inter-pod links)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, multi_pod: bool = False):
     """Small mesh for CI-scale dry-run tests (requires >= n_data*n_model
     host devices)."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return make_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

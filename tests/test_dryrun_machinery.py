@@ -22,15 +22,13 @@ from repro.models.sharding import mesh_context
 from repro.launch.specs import input_specs
 from repro.launch.hlo_analysis import analyze
 from repro.models.steps import make_train_step, make_decode_step
+from repro.launch.mesh import make_debug_mesh
 
 out = {}
 for name, multi_pod in (("smollm-135m", False), ("granite-moe-3b-a800m", False),
                         ("mamba2-780m", True)):
     cfg = ARCHS[name].reduced()
-    if multi_pod:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    else:
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_debug_mesh(2, 2, multi_pod=multi_pod)
     shape = ShapeSpec("t", "train", 64, 8)
     with mesh_context(mesh):
         inputs = input_specs(cfg, shape, mesh)

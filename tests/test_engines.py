@@ -81,6 +81,52 @@ def test_jax_matches_numpy(seed, interference):
         assert tids == sorted(tasks.ids.tolist())
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("interference", [False, True])
+def test_jax_matches_numpy_multitask(seed, interference):
+    """Jobs of 1-3 tasks: job-RP sums differ between jobs of one workload,
+    so the jax engine collapses classes on full rows, not workloads."""
+    rng = np.random.default_rng(seed)
+    tasks = []
+    for j in range(30):
+        w = int(rng.integers(NUM_WORKLOADS))
+        for _ in range(int(rng.integers(1, 4))):
+            tasks.append(make_task(job_id=j, workload=w))
+    ts = TaskSet(tasks)
+    cat = aws_catalog()
+    table = _random_table(seed, default=0.97) if interference else None
+    kw = dict(interference_aware=interference, multi_task_aware=True)
+    c_np = full_reconfiguration(ts, cat, table, engine="numpy", **kw)
+    c_jx = full_reconfiguration(ts, cat, table, engine="jax", **kw)
+    assert c_jx.total_hourly_cost(cat) == pytest.approx(
+        c_np.total_hourly_cost(cat), rel=1e-6)
+    tids = sorted(t for _, ts_ in c_jx.assignments for t in ts_)
+    assert tids == sorted(ts.ids.tolist())
+
+
+def test_jax_exact_fit_with_decimal_demands():
+    """Five tasks whose decimal RAM demands fill the big type exactly: the
+    f32 remainder after four of them sits a few ulps below the fifth's
+    demand, and the fit test must still admit it, as numpy's does."""
+    cat = Catalog.from_types([
+        InstanceType("big", "p3", (8, 64, 488), 24.48),
+        InstanceType("mid", "p3", (4, 32, 244), 12.24),
+        InstanceType("small", "p3", (1, 8, 61), 3.06),
+    ])
+    demands = [(1, 19, 173.6), (1, 22, 175.8), (1, 17, 94.7), (1, 3, 40.8),
+               (1, 3, 3.1)]
+    tasks = TaskSet([
+        Task(task_id=i, job_id=i, workload=i,
+             demands={fam: d if fam == "p3" else (99.0, 999.0, 9999.0)
+                      for fam in FAMILIES})
+        for i, d in enumerate(demands)])
+    kw = dict(interference_aware=False, multi_task_aware=True)
+    c_np = full_reconfiguration(tasks, cat, None, engine="numpy", **kw)
+    c_jx = full_reconfiguration(tasks, cat, None, engine="jax", **kw)
+    assert _canon(c_np) == [(0, (0, 1, 2, 3, 4))]
+    assert _canon(c_jx) == _canon(c_np)
+
+
 def _canon(cfg):
     """Partition-canonical view: the jax engine emits each instance's tasks
     grouped by collapsed class, numpy in pick order."""
