@@ -1,0 +1,7 @@
+"""95th percentile (linear interpolation) of the wall time of ``schedule()``
+over every round of the window (ms)."""
+import numpy as np
+
+
+def read(rec):
+    return float(np.percentile(rec["round_s"], 95)) * 1e3

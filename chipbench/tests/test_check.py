@@ -1,0 +1,100 @@
+"""The comparison itself, on rounds and packs made by hand."""
+import json
+import os
+import types
+
+import numpy as np
+import pytest
+
+from chipbench import check, reference as ref
+from repro.core.cluster_types import TaskSet
+
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs",
+                      "eva-alibaba-1k.json")
+
+
+@pytest.fixture(scope="module")
+def config():
+    with open(CONFIG) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def cat(config):
+    return ref.Catalog(config["catalog"], config["families"])
+
+
+def round_with_twins(plan_tail_type):
+    """Tasks 10 and 12 are alike in workload and demand: 10 runs beside 11
+    on a p3.8xlarge (type 1), 12 is pending and Partial packs it alone."""
+    rows = np.array([[1.0, 14.0, 196.3], [1.0, 4.0, 20.0], [1.0, 14.0, 196.3]])
+    demand = np.repeat(rows[:, None, :], 3, axis=1)
+    ts = TaskSet.from_arrays(np.array([10, 11, 12]), np.array([1, 2, 3]),
+                             np.zeros(3, np.int64), demand)
+    view = types.SimpleNamespace(
+        tasks=ts, live=[types.SimpleNamespace(type_index=1, task_ids=(10, 11))])
+    repack = check.PackCall(demand[2:], np.zeros(1, np.int64), [(1, [0])])
+    plan = [(1, (10, 11)), (plan_tail_type, (12,))]
+    return check.RoundRecord(view, {}, [repack], plan)
+
+
+def test_a_pending_twin_of_a_kept_task_is_no_violation(cat, config):
+    r = check.check_round(round_with_twins(1), cat, config)
+    assert {k: r[k] for k in ("pack_cost_gap", "pack_misplaced",
+                              "pack_mismatched", "plan_violations",
+                              "rows_not_judged")} == {
+        "pack_cost_gap": 0.0, "pack_misplaced": 0, "pack_mismatched": 0,
+        "plan_violations": 0, "rows_not_judged": 0}
+
+
+def test_a_plan_that_is_not_the_pack_is_a_violation(cat, config):
+    r = check.check_round(round_with_twins(0), cat, config)
+    assert r["plan_violations"] == 1
+
+
+@pytest.mark.parametrize("shortfall, close", [(5e-6, True), (1e-4, False)])
+def test_break_even_within_the_float32_band_is_not_judged(cat, shortfall,
+                                                          close):
+    """Two tasks whose TNRP falls ``shortfall`` (relative) short of a
+    c7i.large's cost: float64 does not keep them, and inside the float32
+    band the pack says it is not to be judged."""
+    k = cat.names.index("c7i.large")
+    cost = cat.costs[k]
+    demand = np.zeros((2, 3, 3))
+    demand[:, :, 1] = 1.0
+    rp = np.full(2, cost / 2)
+    pairwise = np.array([[1.0 - shortfall]])
+    only = ref.Catalog([{"name": "c7i.large", "family": "c7i",
+                         "capacity": cat.caps[k].tolist(),
+                         "hourly_cost": cost}], ["p3", "c7i", "r7i"])
+    seen = []
+    got = ref.pack(demand, np.zeros(2, np.int64), rp, rp, only, pairwise,
+                   close=seen)
+    assert got == []
+    assert bool(seen) == close
+
+
+def test_migration_cost_prices_moves_and_launches(cat):
+    """Live: instance 7 (type 1) holds tasks 10 and 11.  The plan keeps 10
+    there, moves 11 to a new type-0 instance and starts pending task 12 on
+    a new type-2 instance."""
+    live = [(7, 1, (10, 11))]
+    plan = [(1, (10,)), (0, (11,)), (2, (12,))]
+    delay = [0.0, 36.0, 72.0]
+    got = ref.migration_cost(live, plan, {10: 1, 11: 2, 12: 1}, cat, delay,
+                             360.0)
+    c = cat.costs
+    want = (0.1 * (c[0] + c[2])               # two launches
+            + 0.02 * (c[0] + c[1])            # 11 moves from type 1
+            + 0.01 * c[2])                    # 12 starts from pending
+    assert got == pytest.approx(want, rel=1e-12)
+
+
+@pytest.mark.parametrize("values, want", [
+    ((2.0, 1.5, 1.0, 0.0, 3600.0), False),  # Full saves 1 $/h, moves cost 1.5
+    ((3.0, 1.0, 1.0, 0.0, 3600.0), True),
+    ((2.0, 1.5, 1.0, 0.0, 7200.0), True),
+    ((2.0, 2.0, 1.0, 1.0, 3600.0), None),   # a tie
+])
+def test_the_ensemble_adopts_full_when_it_is_worth_more(values, want):
+    assert ref.adopt_full(*values) == want
