@@ -136,6 +136,7 @@ from ..core.scheduler import SchedulerBase, SchedulerView
 from ..core.serving import p99_latency_ms_np, utility_np
 from ..core.workloads import M_TRUE, WORKLOADS, checkpoint_size_gb
 from ..obs import events as obs_ev
+from ..obs import profiler as _prof
 from ..policies.pressure import (CREDIT, DEADLINE, SLO, SPOT, PressureBus,
                                  PressureSignal)
 from .fleet import SlotTable
@@ -1422,14 +1423,28 @@ class Simulator:
         return sorted(out)
 
     def _run_round(self):
-        self._report_throughputs()
-        tids = self._live_task_ids()
-        if not tids:
+        with _prof.span("sim.view"):
+            self._report_throughputs()
+            tids = self._live_task_ids()
+            view = self._round_view(tids) if tids else None
+        if view is None:
             # nothing to schedule; terminate any empty instances
             for inst in self._live_instances():
                 if not inst.assigned and not inst.residents:
                     self._terminate(inst, "idle")
             return
+        n_tasks, n_pending = len(view.tasks), len(view.pending_ids)
+        config = self.scheduler.schedule(view)
+        if self._rec is not None:
+            self._emit_round(n_tasks, n_pending)
+        self._round_index += 1
+        if self._commit:
+            self._apply_commitment_orders()
+        with _prof.span("sim.execute"):
+            self._execute_config(config)
+
+    def _round_view(self, tids: List[int]) -> SchedulerView:
+        """The round's snapshot of the ``tids`` live tasks."""
         taskset = TaskSet([self.tasks[t].task for t in tids])
         pending = {t for t in tids if self.tasks[t].dst is None}
         live_view = [LiveInstance(i.iid, i.type_index, tuple(sorted(i.assigned)))
@@ -1482,7 +1497,7 @@ class Simulator:
                 specs[jid] = spec
                 if js.svc_risk:
                     slo_risk.add(jid)
-        view = SchedulerView(
+        return SchedulerView(
             time=self.now, tasks=taskset, pending_ids=pending, live=live_view,
             task_workload={t: self.tasks[t].workload for t in tids},
             remaining_s=remaining or None, revoked=revoked or None,
@@ -1493,13 +1508,6 @@ class Simulator:
             service=service or None, service_rps=service_rps or None,
             service_capacity=service_cap or None, slo_risk=slo_risk or None,
             service_specs=specs or None)
-        config = self.scheduler.schedule(view)
-        if self._rec is not None:
-            self._emit_round(len(tids), len(pending))
-        self._round_index += 1
-        if self._commit:
-            self._apply_commitment_orders()
-        self._execute_config(config)
 
     def _emit_round(self, n_tasks: int, n_pending: int) -> None:
         """ROUND event + the per-round gauge samples (flight recorder on)."""
@@ -1866,6 +1874,17 @@ class Simulator:
                 self._schedule_next_round()
 
     def run(self) -> Metrics:
+        """Runs the simulation to its end.  A flight recorder's profiler is
+        the module-level span hook for the run, unless one is active."""
+        if self._rec is None or _prof.active() is not None:
+            return self._run()
+        _prof.activate(self._rec.profiler)
+        try:
+            return self._run()
+        finally:
+            _prof.activate(None)
+
+    def _run(self) -> Metrics:
         while self._heap:
             t, kind, _, payload = heapq.heappop(self._heap)
             if t > self.cfg.max_time_s:

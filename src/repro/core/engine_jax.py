@@ -252,51 +252,56 @@ def pack_jax(demand_by_family: np.ndarray, workloads: np.ndarray,
     T = demand_by_family.shape[0]
     if T == 0:
         return []
-    jr = rp if job_rp is None else job_rp  # single-task == jobrp ≡ rp
-    dt = jax.dtypes.canonicalize_dtype(np.float64)
-    merge = bool(np.all(pairwise == 1.0))
-    inv, cw, crp, cjr, cdemand, counts = _collapse_classes(
-        np.asarray(workloads), np.asarray(rp), np.asarray(jr),
-        np.asarray(demand_by_family), merge)
-    C = counts.size
-    order_rows = np.argsort(inv, kind="stable")  # ascending rows per class
-    starts = np.concatenate([[0], np.cumsum(counts)])
+    with _prof.span("pack.prepare") as sp:
+        jr = rp if job_rp is None else job_rp  # single-task == jobrp ≡ rp
+        dt = jax.dtypes.canonicalize_dtype(np.float64)
+        merge = bool(np.all(pairwise == 1.0))
+        inv, cw, crp, cjr, cdemand, counts = _collapse_classes(
+            np.asarray(workloads), np.asarray(rp), np.asarray(jr),
+            np.asarray(demand_by_family), merge)
+        C = counts.size
+        order_rows = np.argsort(inv, kind="stable")  # ascending rows/class
+        starts = np.concatenate([[0], np.cumsum(counts)])
 
-    # pad class axis / row queues to power-of-two buckets so jit shapes (and
-    # compilations) stay bounded as fleet composition changes round to round
-    c_pad = _pow2(C, 4)
-    m_cap = _pow2(int(counts.max()), 8)
-    rows_pad = np.full((c_pad, m_cap), T, np.int32)
-    for c in range(C):
-        rows_pad[c, :counts[c]] = order_rows[starts[c]:starts[c + 1]]
-    pad = c_pad - C
-    counts_p = np.concatenate([counts, np.zeros(pad, np.int32)])
-    cw_p = np.concatenate([cw, np.zeros(pad, np.int64)]).astype(np.int32)
-    crp_p = np.concatenate([crp, np.zeros(pad)]).astype(dt)
-    cjr_p = np.concatenate([cjr, np.zeros(pad)]).astype(dt)
-    cdem_p = np.concatenate(
-        [cdemand, np.zeros((pad,) + cdemand.shape[1:])]).astype(dt)
+        # pad class axis / row queues to power-of-two buckets so jit shapes
+        # (and compilations) stay bounded as fleet composition changes round
+        # to round
+        c_pad = _pow2(C, 4)
+        m_cap = _pow2(int(counts.max()), 8)
+        rows_pad = np.full((c_pad, m_cap), T, np.int32)
+        for c in range(C):
+            rows_pad[c, :counts[c]] = order_rows[starts[c]:starts[c + 1]]
+        pad = c_pad - C
+        counts_p = np.concatenate([counts, np.zeros(pad, np.int32)])
+        cw_p = np.concatenate([cw, np.zeros(pad, np.int64)]).astype(np.int32)
+        crp_p = np.concatenate([crp, np.zeros(pad)]).astype(dt)
+        cjr_p = np.concatenate([cjr, np.zeros(pad)]).astype(dt)
+        cdem_p = np.concatenate(
+            [cdemand, np.zeros((pad,) + cdemand.shape[1:])]).astype(dt)
 
-    ks = [k for k in catalog.order_desc.tolist()
-          if type_mask is None or bool(np.asarray(type_mask)[k])]
-    if not ks:
-        return []
-    costs = catalog.costs[ks].astype(dt)
-    caps = catalog.capacities[ks].astype(dt)
-    fams = catalog.family_ids[ks].astype(np.int32)
-    if region_budget is not None:
-        rids = catalog.region_ids[ks].astype(np.int32)
-        budget0 = np.minimum(region_budget, _BIG_I).astype(np.int32)
-    else:
-        rids = np.zeros(len(ks), np.int32)
-        budget0 = np.array([_BIG_I], np.int32)
+        ks = [k for k in catalog.order_desc.tolist()
+              if type_mask is None or bool(np.asarray(type_mask)[k])]
+        if not ks:
+            return []
+        costs = catalog.costs[ks].astype(dt)
+        caps = catalog.capacities[ks].astype(dt)
+        fams = catalog.family_ids[ks].astype(np.int32)
+        if region_budget is not None:
+            rids = catalog.region_ids[ks].astype(np.int32)
+            budget0 = np.minimum(region_budget, _BIG_I).astype(np.int32)
+        else:
+            rids = np.zeros(len(ks), np.int32)
+            budget0 = np.array([_BIG_I], np.int32)
 
-    P = jnp.asarray(pairwise, dt)
-    logP = jnp.log(jnp.maximum(P, 1e-9))
-    max_fills = _pow2(max(256, T // 2 + 8), 256)
+        P = jnp.asarray(pairwise, dt)
+        logP = jnp.log(jnp.maximum(P, 1e-9))
+        max_fills = _pow2(max(256, T // 2 + 8), 256)
+    if sp is not None:
+        sp.tags["classes"] = C
     cache_size = getattr(_pack_all_types, "_cache_size", lambda: -1)
     while True:  # record count ≤ T, so doubling always terminates
-        n_cached = cache_size()
+        # the jit cache is read only for the span's stage tag
+        n_cached = cache_size() if _prof.active() is not None else -1
         # the module-level span hook is a shared nullcontext (sp is None)
         # unless a profiler was activated; the bool(overflow) host sync sits
         # inside the span so device time is part of the measurement
@@ -319,28 +324,31 @@ def pack_jax(demand_by_family: np.ndarray, workloads: np.ndarray,
             break
         max_fills *= 2
 
-    nrec = int(n_rec)
-    rt = np.asarray(rec_type[:nrec])
-    rr = np.asarray(rec_rep[:nrec])
-    rc = np.asarray(rec_comp[:nrec])
-    ptr = starts[:-1].copy()
-    out: List[Tuple[int, List[int]]] = []
-    for i in range(nrec):
-        k = ks[int(rt[i])]
-        rep = int(rr[i])
-        comp = rc[i]
-        cls = np.nonzero(comp[:C])[0]
-        chunks = []
-        for c in cls:
-            n = int(comp[c]) * rep
-            chunks.append(order_rows[ptr[c]:ptr[c] + n]
-                          .reshape(rep, int(comp[c])))
-            ptr[c] += n
-        allrows = np.concatenate(chunks, axis=1)
-        for j in range(rep):
-            out.append((k, allrows[j].tolist()))
-    if region_budget is not None:
-        consumed = budget0.astype(np.int64) - np.asarray(budget_out,
-                                                         dtype=np.int64)
-        region_budget -= consumed  # in place: callers track remaining budget
+    with _prof.span("pack.readback") as sp:
+        nrec = int(n_rec)
+        rt = np.asarray(rec_type[:nrec])
+        rr = np.asarray(rec_rep[:nrec])
+        rc = np.asarray(rec_comp[:nrec])
+        ptr = starts[:-1].copy()
+        out: List[Tuple[int, List[int]]] = []
+        for i in range(nrec):
+            k = ks[int(rt[i])]
+            rep = int(rr[i])
+            comp = rc[i]
+            cls = np.nonzero(comp[:C])[0]
+            chunks = []
+            for c in cls:
+                n = int(comp[c]) * rep
+                chunks.append(order_rows[ptr[c]:ptr[c] + n]
+                              .reshape(rep, int(comp[c])))
+                ptr[c] += n
+            allrows = np.concatenate(chunks, axis=1)
+            for j in range(rep):
+                out.append((k, allrows[j].tolist()))
+        if region_budget is not None:
+            consumed = budget0.astype(np.int64) - np.asarray(budget_out,
+                                                             dtype=np.int64)
+            region_budget -= consumed  # in place: callers track the rest
+    if sp is not None:
+        sp.tags["records"] = nrec
     return out

@@ -20,6 +20,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import profiler as _prof
 from .catalog import Catalog
 from .cluster_types import Assignment, ClusterConfig, TaskSet
 from .reservation_price import job_rp_sums, reservation_prices
@@ -225,17 +226,18 @@ def full_reconfiguration(tasks: TaskSet, catalog: Catalog,
         big = np.iinfo(np.int64).max
         region_budget = np.array([big if c is None else int(c)
                                   for c in region_caps], dtype=np.int64)
-    if rp is None:
-        rp = reservation_prices(tasks, catalog, type_mask=type_mask)
-    if multi_task_aware and job_rp is None:
-        job_rp = job_rp_sums(tasks, rp)
-    if not multi_task_aware:
-        job_rp = None
-    if interference_aware and table is not None:
-        pairwise = table.pairwise_matrix()
-    else:
-        n = int(tasks.workloads.max()) + 1 if len(tasks) else 1
-        pairwise = np.ones((max(n, 1), max(n, 1)))
+    with _prof.span("pack.prepare"):
+        if rp is None:
+            rp = reservation_prices(tasks, catalog, type_mask=type_mask)
+        if multi_task_aware and job_rp is None:
+            job_rp = job_rp_sums(tasks, rp)
+        if not multi_task_aware:
+            job_rp = None
+        if interference_aware and table is not None:
+            pairwise = table.pairwise_matrix()
+        else:
+            n = int(tasks.workloads.max()) + 1 if len(tasks) else 1
+            pairwise = np.ones((max(n, 1), max(n, 1)))
     if engine == "jax":
         from .engine_jax import pack_jax
         packer = pack_jax

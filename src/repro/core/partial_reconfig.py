@@ -44,6 +44,7 @@ from typing import (Callable, Iterable, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from ..obs import profiler as _prof
 from .catalog import Catalog
 from .cluster_types import Assignment, ClusterConfig, TaskSet
 from .full_reconfig import EPS, evaluate_assignments, full_reconfiguration
@@ -71,86 +72,96 @@ def partial_reconfiguration(tasks: TaskSet, live_assignments: Sequence[Assignmen
         catalog = catalog.at(time_s)  # all downstream prices from one instant
     if credit_horizon_s is not None:
         catalog = catalog.credit_priced(credit_horizon_s)
-    live_task_ids = {t for _, tids in live_assignments for t in tids}
     # Drop completed tasks from live assignments.
-    system_ids = set(tasks.ids.tolist())
-    trimmed: List[Assignment] = []
-    for k, tids in live_assignments:
-        alive = tuple(t for t in tids if t in system_ids)
-        if alive:
-            trimmed.append((k, alive))
+    with _prof.span("partial.keep_test") as sp:
+        system_ids = set(tasks.ids.tolist())
+        trimmed: List[Assignment] = []
+        for k, tids in live_assignments:
+            alive = tuple(t for t in tids if t in system_ids)
+            if alive:
+                trimmed.append((k, alive))
 
-    repack: Set[int] = set(pending_ids) & system_ids
-    keep: List[Assignment] = []
-    if trimmed:
-        tnrps, costs = evaluate_assignments(trimmed, tasks, catalog, table,
+        repack: Set[int] = set(pending_ids) & system_ids
+        keep: List[Assignment] = []
+        if trimmed:
+            tnrps, costs = evaluate_assignments(trimmed, tasks, catalog,
+                                                table, multi_task_aware,
+                                                type_mask=type_mask)
+            for (k, tids), s, c in zip(trimmed, tnrps, costs):
+                # keep_bonus amortizes the cost of *moving* this set
+                # (multi-region: checkpoint transfer + egress + relaunch
+                # over the D-hat horizon) into the keep test: evicting for
+                # a cheaper market only pays off if the price gap beats
+                # the migration penalty.
+                slack = keep_bonus(k, tids) if keep_bonus is not None else 0.0
+                if s >= c - slack - EPS:
+                    keep.append((k, tids))
+                else:  # no longer cost-efficient -> evict for re-packing
+                    repack |= set(tids)
+    if sp is not None:
+        sp.tags.update(kept=len(keep), evicted=len(trimmed) - len(keep))
+
+    if not repack:
+        return ClusterConfig(keep)
+
+    with _prof.span("partial.best_fit") as sp:
+        n_pending, evals = len(repack), 0
+        rp_all = reservation_prices(tasks, catalog, type_mask=type_mask)
+        job_rp_all = job_rp_sums(tasks, rp_all) if multi_task_aware else None
+
+        # First, best-fit repack tasks into spare capacity on KEPT instances
+        # (no extra provisioning, no migration of existing tenants) whenever
+        # the grown set stays cost-efficient under TNRP.
+        keep = [list(a) for a in keep]
+        for tid in sorted(repack, key=lambda t: -rp_all[tasks.row(t)]):
+            row = tasks.row(tid)
+            best, best_left = -1, np.inf
+            for i, (k, tids) in enumerate(keep):
+                fam = catalog.family_ids[k]
+                used = tasks.demand_by_family[
+                    [tasks.row(x) for x in tids], fam, :].sum(axis=0)
+                d = tasks.demand_by_family[row, fam, :]
+                if np.any(used + d > catalog.capacities[k] + EPS):
+                    continue
+                grown = (k, tuple(tids) + (tid,))
+                s, c = evaluate_assignments([grown], tasks, catalog, table,
                                             multi_task_aware,
                                             type_mask=type_mask)
-        for (k, tids), s, c in zip(trimmed, tnrps, costs):
-            # keep_bonus amortizes the cost of *moving* this set (multi-region:
-            # checkpoint transfer + egress + relaunch over the D-hat horizon)
-            # into the keep test: evicting for a cheaper market only pays off
-            # if the price gap beats the migration penalty.
-            slack = keep_bonus(k, tids) if keep_bonus is not None else 0.0
-            if s >= c - slack - EPS:
-                keep.append((k, tids))
-            else:  # no longer cost-efficient -> evict for re-packing
-                repack |= set(tids)
+                evals += 1
+                if s[0] < c[0] - EPS:
+                    continue
+                left = float(((catalog.capacities[k] - used - d)
+                              / np.maximum(catalog.capacities[k], 1.0)).sum())
+                if left < best_left:
+                    best, best_left = i, left
+            if best >= 0:
+                keep[best][1] = tuple(keep[best][1]) + (tid,)
+                repack.discard(tid)
+        keep = [(k, tuple(tids)) for k, tids in keep]
+    if sp is not None:
+        sp.tags.update(pending=n_pending, kept=len(keep), evals=evals)
 
     if not repack:
         return ClusterConfig(keep)
-
-    rp_all = reservation_prices(tasks, catalog, type_mask=type_mask)
-    job_rp_all = job_rp_sums(tasks, rp_all) if multi_task_aware else None
-
-    # First, best-fit repack tasks into spare capacity on KEPT instances
-    # (no extra provisioning, no migration of existing tenants) whenever the
-    # grown set stays cost-efficient under TNRP.
-    keep = [list(a) for a in keep]
-    for tid in sorted(repack, key=lambda t: -rp_all[tasks.row(t)]):
-        row = tasks.row(tid)
-        best, best_left = -1, np.inf
-        for i, (k, tids) in enumerate(keep):
-            fam = catalog.family_ids[k]
-            used = tasks.demand_by_family[
-                [tasks.row(x) for x in tids], fam, :].sum(axis=0)
-            d = tasks.demand_by_family[row, fam, :]
-            if np.any(used + d > catalog.capacities[k] + EPS):
-                continue
-            grown = (k, tuple(tids) + (tid,))
-            s, c = evaluate_assignments([grown], tasks, catalog, table,
-                                        multi_task_aware,
-                                        type_mask=type_mask)
-            if s[0] < c[0] - EPS:
-                continue
-            left = float(((catalog.capacities[k] - used - d)
-                          / np.maximum(catalog.capacities[k], 1.0)).sum())
-            if left < best_left:
-                best, best_left = i, left
-        if best >= 0:
-            keep[best][1] = tuple(keep[best][1]) + (tid,)
-            repack.discard(tid)
-    keep = [(k, tuple(tids)) for k, tids in keep]
-
-    if not repack:
-        return ClusterConfig(keep)
-    # Kept instances consume their region's instance-count budget; the
-    # Algorithm-1 repack only gets the remaining headroom.
-    sub_caps = region_caps
-    if region_caps is not None and catalog.region_ids is not None:
-        kept_per_region = [0] * len(region_caps)
-        for k, _ in keep:
-            kept_per_region[catalog.region_of(k)] += 1
-        sub_caps = [None if c is None else max(int(c) - kept_per_region[r], 0)
-                    for r, c in enumerate(region_caps)]
-    sub = tasks.subset(sorted(repack))
-    rows = np.array([tasks.row(t) for t in sub.ids.tolist()])
-    packed = full_reconfiguration(
-        sub, catalog, table, interference_aware=interference_aware,
-        multi_task_aware=multi_task_aware, engine=engine,
-        rp=rp_all[rows],
-        job_rp=job_rp_all[rows] if job_rp_all is not None else None,
-        type_mask=type_mask, region_caps=sub_caps)
+    with _prof.span("partial.repack"):
+        # Kept instances consume their region's instance-count budget; the
+        # Algorithm-1 repack only gets the remaining headroom.
+        sub_caps = region_caps
+        if region_caps is not None and catalog.region_ids is not None:
+            kept_per_region = [0] * len(region_caps)
+            for k, _ in keep:
+                kept_per_region[catalog.region_of(k)] += 1
+            sub_caps = [None if c is None
+                        else max(int(c) - kept_per_region[r], 0)
+                        for r, c in enumerate(region_caps)]
+        sub = tasks.subset(sorted(repack))
+        rows = np.array([tasks.row(t) for t in sub.ids.tolist()])
+        packed = full_reconfiguration(
+            sub, catalog, table, interference_aware=interference_aware,
+            multi_task_aware=multi_task_aware, engine=engine,
+            rp=rp_all[rows],
+            job_rp=job_rp_all[rows] if job_rp_all is not None else None,
+            type_mask=type_mask, region_caps=sub_caps)
     return ClusterConfig(keep + packed.assignments)
 
 

@@ -39,6 +39,7 @@ import dataclasses
 import warnings
 from typing import Dict, List, Optional, Sequence, Set
 
+from ..obs import profiler as _prof
 from ..obs.trace import DecisionRecord, KeepEntry
 from .catalog import Catalog
 from .cluster_types import ClusterConfig, TaskSet
@@ -326,32 +327,41 @@ class EvaScheduler(SchedulerBase):
 
     # -- scheduling ---------------------------------------------------------
     def schedule(self, view: SchedulerView) -> ClusterConfig:
+        with _prof.span("sched.round") as sp:
+            if sp is not None:
+                sp.tags.update(n_tasks=len(view.tasks),
+                               n_pending=len(view.pending_ids))
+            return self._schedule(view)
+
+    def _schedule(self, view: SchedulerView) -> ClusterConfig:
         self.rounds += 1
         table = self.table if self.interference_aware else None
         kw = dict(interference_aware=self.interference_aware,
                   multi_task_aware=self.multi_task_aware, engine=self.engine)
         d_hat = self.estimator.d_hat()
-        # Admission layers first: jobs a controller holds are removed from
-        # the round's task set before anything is priced, so Algorithm 1
-        # never provisions for them.
-        view, resumed = self.stack.pre_round(view, d_hat)
-        # Catalog pipeline: snapshot transforms (spot re-pricing at the
-        # current time), then planning transforms (credit-effective
-        # $/throughput) — `raw` bills, `cat` plans.
-        raw, cat = self.stack.plan(self.catalog, view, d_hat)
-        if self._rec is None:
-            keep_bonus = self.stack.keep_bonus(raw, cat, view)
-        else:
-            # identical fold, but keep the per-layer parts so the decision
-            # trace can decompose the summed slack by contributing layer
-            # (each layer's hook still runs exactly once)
-            self._trace_parts = self.stack.keep_bonus_parts(raw, cat, view)
-            keep_bonus = self.stack.combine(
-                fn for _, fn in self._trace_parts)
-            self._trace_pending = self._trace_begin(view, cat, d_hat)
-        mask, caps = self.stack.mask, self.stack.caps
+        with _prof.span("sched.policies"):
+            # Admission layers first: jobs a controller holds are removed
+            # from the round's task set before anything is priced, so
+            # Algorithm 1 never provisions for them.
+            view, resumed = self.stack.pre_round(view, d_hat)
+            # Catalog pipeline: snapshot transforms (spot re-pricing at the
+            # current time), then planning transforms (credit-effective
+            # $/throughput) — `raw` bills, `cat` plans.
+            raw, cat = self.stack.plan(self.catalog, view, d_hat)
+            if self._rec is None:
+                keep_bonus = self.stack.keep_bonus(raw, cat, view)
+            else:
+                # identical fold, but keep the per-layer parts so the
+                # decision trace can decompose the summed slack by
+                # contributing layer (each layer's hook still runs once)
+                self._trace_parts = self.stack.keep_bonus_parts(raw, cat,
+                                                                view)
+                keep_bonus = self.stack.combine(
+                    fn for _, fn in self._trace_parts)
+                self._trace_pending = self._trace_begin(view, cat, d_hat)
+            mask, caps = self.stack.mask, self.stack.caps
 
-        evac = self.stack.evacuate(raw, view)
+            evac = self.stack.evacuate(raw, view)
         if evac or resumed:
             if self._trace_pending is not None:
                 self._trace_pending.kind = "forced-partial"
@@ -366,9 +376,10 @@ class EvaScheduler(SchedulerBase):
             self._trace_pending.keep_table = self._trace_keep_table(
                 view.live, view.tasks, cat, table, mask)
         if self.mode == "full-only":
-            cfg = full_reconfiguration(view.tasks, cat, table,
-                                       type_mask=mask,
-                                       region_caps=caps, **kw)
+            with _prof.span("full.candidate"):
+                cfg = full_reconfiguration(view.tasks, cat, table,
+                                           type_mask=mask,
+                                           region_caps=caps, **kw)
             self.full_adoptions += 1
             if self._trace_pending is not None:
                 self._trace_pending.kind = "full-only"
@@ -382,24 +393,27 @@ class EvaScheduler(SchedulerBase):
             if self._trace_pending is not None:
                 self._trace_pending.kind = "partial-only"
             return self._finish(partial, view, cat)
-        full = full_reconfiguration(view.tasks, cat, table,
-                                    type_mask=mask,
-                                    region_caps=caps, **kw)
+        with _prof.span("full.candidate"):
+            full = full_reconfiguration(view.tasks, cat, table,
+                                        type_mask=mask,
+                                        region_caps=caps, **kw)
 
-        s_f = instantaneous_saving(*evaluate_assignments(
-            full.assignments, view.tasks, cat, table,
-            self.multi_task_aware, type_mask=mask))
-        s_p = instantaneous_saving(*evaluate_assignments(
-            partial.assignments, view.tasks, cat, table,
-            self.multi_task_aware, type_mask=mask))
-        m_f = migration_cost(diff_configs(view.live, full), view.live,
-                             cat, view.task_workload,
-                             self.migration_delay_scale,
-                             task_ckpt_region=view.task_ckpt_region)
-        m_p = migration_cost(diff_configs(view.live, partial), view.live,
-                             cat, view.task_workload,
-                             self.migration_delay_scale,
-                             task_ckpt_region=view.task_ckpt_region)
+        with _prof.span("ensemble.saving"):
+            s_f = instantaneous_saving(*evaluate_assignments(
+                full.assignments, view.tasks, cat, table,
+                self.multi_task_aware, type_mask=mask))
+            s_p = instantaneous_saving(*evaluate_assignments(
+                partial.assignments, view.tasks, cat, table,
+                self.multi_task_aware, type_mask=mask))
+        with _prof.span("ensemble.migration"):
+            m_f = migration_cost(diff_configs(view.live, full), view.live,
+                                 cat, view.task_workload,
+                                 self.migration_delay_scale,
+                                 task_ckpt_region=view.task_ckpt_region)
+            m_p = migration_cost(diff_configs(view.live, partial),
+                                 view.live, cat, view.task_workload,
+                                 self.migration_delay_scale,
+                                 task_ckpt_region=view.task_ckpt_region)
         decision = choose(s_f, m_f, s_p, m_p, self.estimator.d_hat())
         self.decisions.append(decision)
         if self._trace_pending is not None:
@@ -429,7 +443,8 @@ class EvaScheduler(SchedulerBase):
         resumed jobs' tasks are already in ``pending_ids``.  The type mask
         is the stack's drain mask (standing mask AND any drain
         restrictions, e.g. steady-types-only for credit drains)."""
-        mask = self.stack.drain_mask(raw, view)
+        with _prof.span("sched.policies"):
+            mask = self.stack.drain_mask(raw, view)
         self.forced_partials += 1
         if self.incremental:
             from ..policies.pressure import dirty_instance_ids
@@ -465,9 +480,11 @@ class EvaScheduler(SchedulerBase):
     def _finish(self, config: ClusterConfig, view: SchedulerView,
                 cat: Catalog) -> ClusterConfig:
         if self._rec is None:
-            return self.stack.refine(config, view, cat)
+            with _prof.span("sched.policies"):
+                return self.stack.refine(config, view, cat)
         before = self._numeric_summary()
-        config = self.stack.refine(config, view, cat)
+        with _prof.span("sched.policies"):
+            config = self.stack.refine(config, view, cat)
         after = self._numeric_summary()
         trace = self._trace_pending
         if trace is not None:

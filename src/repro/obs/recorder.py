@@ -104,5 +104,6 @@ class FlightRecorder:
                     from .profiler import Span
                     rec.profiler.spans.append(Span(
                         d["name"], float(d["start_s"]),
-                        float(d["duration_s"]), d.get("tags", {})))
+                        float(d["duration_s"]), d.get("tags", {}),
+                        d.get("parent"), d.get("round")))
         return rec

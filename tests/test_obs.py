@@ -11,6 +11,7 @@ aggregated cost ledger, decision trace, metrics registry + Prometheus
 export, wall-clock profiler, JSONL round-trip, structured reporter) and
 drives the ``tools/explain.py`` replay CLI end-to-end on a real trace.
 """
+import contextlib
 import io
 import json
 import subprocess
@@ -229,6 +230,272 @@ def test_profiler_spans_and_module_hook():
     assert p.by_name("hooked")
 
 
+def test_profiler_links_parent_and_round():
+    p = Profiler()
+    with p.span("sim.view"):
+        pass
+    for _ in range(2):
+        with p.span("sched.round"):
+            with p.span("full.candidate"):
+                with p.span("jax_pack"):
+                    pass
+            with p.span("ensemble.saving"):
+                pass
+    by = {(s.name, s.round): i for i, s in enumerate(p.spans)}
+    assert p.spans[by[("sim.view", None)]].parent is None
+    for r in (0, 1):
+        top = by[("sched.round", r)]
+        assert p.spans[top].parent is None
+        assert p.spans[by[("full.candidate", r)]].parent == top
+        assert p.spans[by[("ensemble.saving", r)]].parent == top
+        assert p.spans[by[("jax_pack", r)]].parent == \
+            by[("full.candidate", r)]
+    assert len(by) == len(p.spans) == 9  # every span once, rounds 0 and 1
+    inner, outer = p.spans[by[("jax_pack", 0)]], p.spans[by[("sched.round", 0)]]
+    assert outer.start_s <= inner.start_s
+    assert inner.start_s + inner.duration_s <= \
+        outer.start_s + outer.duration_s + 1e-9
+
+
+def test_profiler_tags_given_and_set():
+    p = Profiler()
+    prof_mod.activate(p)
+    try:
+        with prof_mod.span("partial.best_fit", pending=3) as sp:
+            pass
+        if sp is not None:
+            sp.tags["evals"] = 7
+    finally:
+        prof_mod.activate(None)
+    assert p.spans[0].tags == {"pending": 3, "evals": 7}
+    d = p.spans[0].to_dict()
+    assert d["tags"] == {"pending": 3, "evals": 7} and "parent" not in d
+
+
+def test_profiler_annotates_only_when_asked(monkeypatch):
+    # annotate=False: jax.profiler is neither imported nor called
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    p = Profiler()
+    with p.span("sched.round"):
+        with p.span("jax_pack"):
+            pass
+    assert [s.name for s in p.spans] == ["jax_pack", "sched.round"]
+    with pytest.raises(ImportError):
+        Profiler(annotate=True)
+    # annotate=True: one TraceAnnotation of the span's name around each span
+    written = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            written.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            written.append(("exit", self.name))
+
+    fake = type(sys)("jax.profiler")
+    fake.TraceAnnotation = Note
+    monkeypatch.setitem(sys.modules, "jax.profiler", fake)
+    p = Profiler(annotate=True)
+    with p.span("sched.round"):
+        with p.span("jax_pack"):
+            pass
+    assert written == [("enter", "sched.round"), ("enter", "jax_pack"),
+                       ("exit", "jax_pack"), ("exit", "sched.round")]
+
+
+def test_profiler_subclass_wrapping_span_keeps_links():
+    # a subclass that wraps ``span`` (as a caller writing its own trace
+    # annotations does) still gets parent and round links
+    seen = []
+
+    class Wrapped(Profiler):
+        @contextlib.contextmanager
+        def span(self, name, **tags):
+            seen.append(name)
+            with super().span(name, **tags) as s:
+                yield s
+
+    p = Wrapped()
+    prof_mod.activate(p)
+    try:
+        with prof_mod.span("sched.round"):
+            with prof_mod.span("full.candidate"):
+                with prof_mod.span("jax_pack"):
+                    pass
+    finally:
+        prof_mod.activate(None)
+    assert seen == ["sched.round", "full.candidate", "jax_pack"]
+    assert [(s.name, s.parent, s.round) for s in p.spans] == [
+        ("jax_pack", 1, 0), ("full.candidate", 2, 0),
+        ("sched.round", None, 0)]
+
+
+@pytest.mark.parametrize("annotate", [False, True])
+def test_profiler_span_closed_by_an_exception(annotate, monkeypatch):
+    open_notes = []
+
+    class Note:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            open_notes.append(self.name)
+
+        def __exit__(self, *exc):
+            assert open_notes.pop() == self.name
+
+    fake = type(sys)("jax.profiler")
+    fake.TraceAnnotation = Note
+    monkeypatch.setitem(sys.modules, "jax.profiler", fake)
+    p = Profiler(annotate=annotate)
+    with pytest.raises(ValueError):
+        with p.span("sched.round"):
+            with p.span("partial.best_fit"):
+                raise ValueError
+    assert not open_notes  # every annotation closed with its span
+    with p.span("sim.execute"):
+        pass
+    with p.span("sched.round"):
+        pass
+    assert [(s.name, s.parent, s.round) for s in p.spans] == [
+        ("partial.best_fit", 1, 0), ("sched.round", None, 0),
+        ("sim.execute", None, None), ("sched.round", None, 1)]
+    assert all(s.duration_s >= 0.0 for s in p.spans)
+
+
+def test_pack_jax_reads_the_jit_cache_only_when_profiled(monkeypatch):
+    from repro.core import TaskSet, engine_jax, full_reconfiguration, make_task
+    jitted = engine_jax._pack_all_types
+    reads = []
+
+    class Counted:
+        def __call__(self, *a, **kw):
+            return jitted(*a, **kw)
+
+        def _cache_size(self):
+            reads.append(1)
+            return jitted._cache_size()
+
+    monkeypatch.setattr(engine_jax, "_pack_all_types", Counted())
+    tasks = TaskSet([make_task(job_id=i, workload=i % 3) for i in range(12)])
+    cat = aws_catalog()
+    off = full_reconfiguration(tasks, cat, engine="jax")
+    assert not reads
+    p = Profiler()
+    prof_mod.activate(p)
+    try:
+        on = full_reconfiguration(tasks, cat, engine="jax")
+    finally:
+        prof_mod.activate(None)
+    assert reads
+    assert on.assignments == off.assignments
+    stages = [s.tags["stage"] for s in p.by_name("jax_pack")]
+    assert stages and set(stages) <= {"compile", "execute"}
+
+
+#: the span tree of one scheduler round: span -> the spans it may open in
+SPAN_PARENTS = {
+    "sched.round": {None}, "sim.view": {None}, "sim.execute": {None},
+    "sched.policies": {"sched.round"}, "partial.keep_test": {"sched.round"},
+    "partial.best_fit": {"sched.round"}, "partial.repack": {"sched.round"},
+    "full.candidate": {"sched.round"}, "ensemble.saving": {"sched.round"},
+    "ensemble.migration": {"sched.round"},
+    "pack.prepare": {"partial.repack", "full.candidate"},
+    "jax_pack": {"partial.repack", "full.candidate"},
+    "pack.readback": {"partial.repack", "full.candidate"},
+}
+
+
+def _fleet_run(engine, profiler, monkeypatch):
+    import itertools
+
+    from repro.cluster import alibaba_like_trace, traces
+    cat = aws_catalog()
+    # the same ids in every run: tie-breaks among equal-priced tasks follow
+    # set order, which follows the ids' values
+    monkeypatch.setattr(traces, "_job_ids", itertools.count(1))
+    monkeypatch.setattr(traces, "_task_ids", itertools.count(1_000_000))
+    jobs = alibaba_like_trace(n_jobs=40, seed=3, mean_interarrival_s=150.0)
+    sched = _Probe(cat, engine=engine)
+    prof_mod.activate(profiler)
+    try:
+        m = Simulator(cat, jobs, sched,
+                      SimConfig(seed=5, max_time_s=4 * 3600.0)).run()
+    finally:
+        prof_mod.activate(None)
+    return sched.probe, m.summary(), m.total_cost
+
+
+@pytest.mark.parametrize("engine", ["numpy", "jax"])
+def test_round_span_tree(engine, monkeypatch):
+    p = Profiler()
+    trace, _, _ = _fleet_run(engine, p, monkeypatch)
+    spans = p.spans
+    rounds = [i for i, s in enumerate(spans) if s.name == "sched.round"]
+    assert len(rounds) == len(trace) > 0
+    seen = set()
+    for s in spans:
+        parent = None if s.parent is None else spans[s.parent]
+        pname = None if parent is None else parent.name
+        assert pname in SPAN_PARENTS[s.name], (s.name, pname)
+        seen.add(s.name)
+        if s.name in ("sim.view", "sim.execute"):
+            assert s.round is None
+        elif s.name == "sched.round":
+            assert s.tags["n_tasks"] >= s.tags["n_pending"] >= 0
+        else:  # inside a round: its round's id, within its parent's time
+            assert s.round == parent.round is not None
+            assert parent.start_s <= s.start_s
+            assert s.start_s + s.duration_s <= \
+                parent.start_s + parent.duration_s + 1e-9
+    assert [spans[i].round for i in rounds] == list(range(len(rounds)))
+    want = set(SPAN_PARENTS)
+    if engine == "numpy":  # no device call, no records to read back
+        want -= {"jax_pack", "pack.readback"}
+    assert seen == want
+    for i in rounds:
+        kids = {s.name for s in spans if s.parent == i}
+        assert {"sched.policies", "partial.keep_test", "full.candidate",
+                "ensemble.saving", "ensemble.migration"} <= kids
+    fits = [s for s in spans if s.name == "partial.best_fit"]
+    assert all(s.tags["pending"] >= 1 and s.tags["evals"] >= 0
+               for s in fits)
+    keeps = p.by_name("partial.keep_test")
+    assert all(s.tags["kept"] >= 0 and s.tags["evicted"] >= 0
+               for s in keeps)
+    if engine == "jax":
+        classes = [s.tags["classes"] for s in p.by_name("pack.prepare")
+                   if "classes" in s.tags]
+        assert classes and min(classes) >= 1
+        assert all(s.tags["records"] >= 1 for s in p.by_name("pack.readback"))
+
+
+@pytest.mark.parametrize("engine,annotate", [("numpy", False),
+                                             ("jax", True)])
+def test_profiling_is_decision_identical(engine, annotate, monkeypatch):
+    off = _fleet_run(engine, None, monkeypatch)
+    p = Profiler(annotate=annotate)
+    on = _fleet_run(engine, p, monkeypatch)
+    assert on[0] == off[0]   # every round's adopted config
+    assert on[1] == off[1]   # metrics summary, key for key
+    assert on[2] == off[2]   # bit-for-bit cost
+    assert p.by_name("sched.round")
+
+
+def test_every_span_the_program_opens_is_named():
+    import re
+    opened = set()
+    for path in (ROOT / "src" / "repro").rglob("*.py"):
+        opened |= set(re.findall(r"""\bspan\(\s*["']([^"']+)["']""",
+                                 path.read_text()))
+    assert "sched.round" in opened and "jax_pack" in opened
+    assert opened <= set(prof_mod.SPANS), opened - set(prof_mod.SPANS)
+    assert set(prof_mod.SPANS) <= opened  # no name kept for nothing
+
+
 # ------------------------------------------------------------- reporter
 def test_reporter_lines_and_json(tmp_path):
     buf = io.StringIO()
@@ -285,3 +552,33 @@ def test_flight_recorder_roundtrip_and_explain_cli(tmp_path):
     assert "round=0" in out
     out = explain("timeline", "--kind", "provision", "--limit", "3")
     assert "kind=provision" in out
+
+
+def test_recorded_run_profiles_its_rounds(tmp_path):
+    rec = FlightRecorder(meta={"scenario": "spot"})
+    _run("spot", recorder=rec)
+    assert prof_mod.active() is None  # restored after the run
+    names = {s.name for s in rec.profiler.spans}
+    assert {"sched.round", "sim.view", "sim.execute"} <= names
+    path = str(tmp_path / "trace.jsonl")
+    rec.save(path)
+    back = FlightRecorder.load(path)
+    assert [(s.parent, s.round) for s in back.profiler.spans] == \
+        [(s.parent, s.round) for s in rec.profiler.spans]
+    r = subprocess.run(
+        [sys.executable, str(ROOT / "tools" / "explain.py"), path,
+         "summary"], capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    rounds = len(rec.profiler.by_name("sched.round"))
+    assert "span sched.round total_s=" in r.stdout
+    assert f"n={rounds}" in r.stdout
+    # an active profiler is left in place and gets the spans
+    outer = Profiler()
+    prof_mod.activate(outer)
+    try:
+        rec2 = FlightRecorder()
+        _run("spot", recorder=rec2)
+        assert prof_mod.active() is outer
+    finally:
+        prof_mod.activate(None)
+    assert not rec2.profiler.spans and outer.by_name("sched.round")
