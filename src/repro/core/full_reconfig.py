@@ -290,11 +290,21 @@ def full_reconfiguration(tasks: TaskSet, catalog: Catalog,
 def evaluate_assignments(assignments: Sequence[Assignment], tasks: TaskSet,
                          catalog: Catalog, table: Optional[ThroughputTable],
                          multi_task_aware: bool = True,
-                         type_mask: Optional[np.ndarray] = None):
+                         type_mask: Optional[np.ndarray] = None, *,
+                         rp: Optional[np.ndarray] = None,
+                         job_rp: Optional[np.ndarray] = None):
     """Per-instance (TNRP(T_i), C_i) for *live* placements, using
-    exact-or-pairwise table lookups of the actual co-location sets."""
-    rp = reservation_prices(tasks, catalog, type_mask=type_mask)
-    job_rp = job_rp_sums(tasks, rp) if multi_task_aware else None
+    exact-or-pairwise table lookups of the actual co-location sets.
+
+    ``rp`` and ``job_rp`` ((T,), over ``tasks``) take the reservation
+    prices and job RP sums a caller already computed from the same catalog
+    and ``type_mask``; left out, they are computed here."""
+    if rp is None:
+        rp = reservation_prices(tasks, catalog, type_mask=type_mask)
+    if not multi_task_aware:
+        job_rp = None
+    elif job_rp is None:
+        job_rp = job_rp_sums(tasks, rp)
     tnrps, costs = [], []
     for k, tids in assignments:
         rows = [tasks.row(t) for t in tids]

@@ -83,10 +83,17 @@ def partial_reconfiguration(tasks: TaskSet, live_assignments: Sequence[Assignmen
 
         repack: Set[int] = set(pending_ids) & system_ids
         keep: List[Assignment] = []
+        if trimmed or repack:
+            # The round's prices, shared by the keep test, every grown-set
+            # evaluation of the best fit and the repack.
+            rp_all = reservation_prices(tasks, catalog, type_mask=type_mask)
+            job_rp_all = (job_rp_sums(tasks, rp_all) if multi_task_aware
+                          else None)
         if trimmed:
             tnrps, costs = evaluate_assignments(trimmed, tasks, catalog,
                                                 table, multi_task_aware,
-                                                type_mask=type_mask)
+                                                type_mask=type_mask,
+                                                rp=rp_all, job_rp=job_rp_all)
             for (k, tids), s, c in zip(trimmed, tnrps, costs):
                 # keep_bonus amortizes the cost of *moving* this set
                 # (multi-region: checkpoint transfer + egress + relaunch
@@ -105,41 +112,51 @@ def partial_reconfiguration(tasks: TaskSet, live_assignments: Sequence[Assignmen
         return ClusterConfig(keep)
 
     with _prof.span("partial.best_fit") as sp:
-        n_pending, evals = len(repack), 0
-        rp_all = reservation_prices(tasks, catalog, type_mask=type_mask)
-        job_rp_all = job_rp_sums(tasks, rp_all) if multi_task_aware else None
-
+        n_pending, scanned, n_fits, evals = len(repack), 0, 0, 0
         # First, best-fit repack tasks into spare capacity on KEPT instances
         # (no extra provisioning, no migration of existing tenants) whenever
-        # the grown set stays cost-efficient under TNRP.
+        # the grown set stays cost-efficient under TNRP.  Each kept
+        # instance's capacity, family and used demand (summed over its
+        # tasks in order, on its own family) are held as rows of arrays, so
+        # one array operation tests a pending task against every instance.
         keep = [list(a) for a in keep]
+        ks = np.array([k for k, _ in keep], dtype=np.int64)
+        fam = catalog.family_ids[ks]
+        caps = catalog.capacities[ks]
+        scale = np.maximum(caps, 1.0)
+        owner = np.repeat(np.arange(len(keep)),
+                          [len(tids) for _, tids in keep])
+        rows = np.array([tasks.row(t) for _, tids in keep for t in tids],
+                        dtype=np.int64)
+        used = np.zeros(caps.shape)
+        np.add.at(used, owner, tasks.demand_by_family[rows, fam[owner], :])
         for tid in sorted(repack, key=lambda t: -rp_all[tasks.row(t)]):
-            row = tasks.row(tid)
-            best, best_left = -1, np.inf
-            for i, (k, tids) in enumerate(keep):
-                fam = catalog.family_ids[k]
-                used = tasks.demand_by_family[
-                    [tasks.row(x) for x in tids], fam, :].sum(axis=0)
-                d = tasks.demand_by_family[row, fam, :]
-                if np.any(used + d > catalog.capacities[k] + EPS):
-                    continue
+            d = tasks.demand_by_family[tasks.row(tid), fam, :]
+            cand = np.flatnonzero(~np.any(used + d > caps + EPS, axis=1))
+            scanned += len(keep)
+            n_fits += cand.size
+            # The fitting instance with the least capacity left over wins,
+            # ties to the lowest index, among those whose grown set passes
+            # the TNRP test: try them in that order, stop at the first.
+            left = ((caps[cand] - used[cand] - d[cand])
+                    / scale[cand]).sum(axis=1)
+            for i in cand[np.argsort(left, kind="stable")].tolist():
+                k, tids = keep[i]
                 grown = (k, tuple(tids) + (tid,))
                 s, c = evaluate_assignments([grown], tasks, catalog, table,
                                             multi_task_aware,
-                                            type_mask=type_mask)
+                                            type_mask=type_mask,
+                                            rp=rp_all, job_rp=job_rp_all)
                 evals += 1
-                if s[0] < c[0] - EPS:
-                    continue
-                left = float(((catalog.capacities[k] - used - d)
-                              / np.maximum(catalog.capacities[k], 1.0)).sum())
-                if left < best_left:
-                    best, best_left = i, left
-            if best >= 0:
-                keep[best][1] = tuple(keep[best][1]) + (tid,)
-                repack.discard(tid)
+                if s[0] >= c[0] - EPS:
+                    keep[i][1] = grown[1]
+                    used[i] += d[i]
+                    repack.discard(tid)
+                    break
         keep = [(k, tuple(tids)) for k, tids in keep]
     if sp is not None:
-        sp.tags.update(pending=n_pending, kept=len(keep), evals=evals)
+        sp.tags.update(pending=n_pending, kept=len(keep), scanned=scanned,
+                       fits=n_fits, evals=evals)
 
     if not repack:
         return ClusterConfig(keep)

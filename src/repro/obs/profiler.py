@@ -32,11 +32,13 @@ SPANS: Dict[str, str] = {
     "sched.policies": "policy-stack hooks: pre_round, plan, keep_bonus, "
                       "evacuate (and drain_mask) before planning; refine "
                       "in _finish",
-    "partial.keep_test": "Partial: trim live instances, evaluate them and "
+    "partial.keep_test": "Partial: trim live instances, the round's "
+                         "reservation prices, evaluate the instances and "
                          "keep or evict each; tags kept, evicted",
-    "partial.best_fit": "Partial: reservation prices, then repack tasks "
-                        "into kept instances' spare capacity; tags "
-                        "pending, kept, evals (grown-set evaluations)",
+    "partial.best_fit": "Partial: repack tasks into kept instances' spare "
+                        "capacity; tags pending, kept, scanned (pending x "
+                        "kept pairs tested for capacity), fits (pairs that "
+                        "fit), evals (grown-set evaluations)",
     "partial.repack": "Partial: Algorithm 1 over the tasks left to repack",
     "full.candidate": "Full Reconfiguration over every live task",
     "ensemble.saving": "ensemble: S_F and S_P (evaluate_assignments)",

@@ -32,11 +32,15 @@ installs the ``test`` extra) drives random axis combinations through the
 laws; seeded fallback tests run the same checker without hypothesis so the
 laws stay pinned even in a bare environment.
 """
+import itertools
+
 import pytest
 
 from repro.autoscale import latest_start_s
+from repro.cluster import traces
 from repro.cluster import (SimConfig, Simulator, burstable_trace,
                            deferrable_trace, physical_trace, portfolio_trace)
+from repro.core import cluster_types
 from repro.core import (CommitmentModel, EvaScheduler, PriceModel, Provider,
                         RequestProfile, ServiceSpec, UtilityCurve,
                         aws_catalog, burstable_demo_catalog,
@@ -351,15 +355,34 @@ def _dicts_close(ds, dv, label):
             f"{label}[{k}]"
 
 
+_ID_COUNTERS = ((cluster_types, "_task_counter"), (traces, "_job_ids"),
+                (traces, "_task_ids"))
+
+
+def _rewind_ids(state=None):
+    """Take (no argument) or restore the next values of the global counters
+    that fresh jobs and tasks draw their ids from.  Two runs of one scenario
+    started from the same values get the same ids.  Plans may depend on the
+    ids' values (Partial takes tasks of equal price in the iteration order
+    of a set of ids), so runs whose ids differ may rightly part ways."""
+    if state is None:
+        state = [next(getattr(mod, name)) for mod, name in _ID_COUNTERS]
+    for (mod, name), value in zip(_ID_COUNTERS, state):
+        setattr(mod, name, itertools.count(value))
+    return state
+
+
 def _check_vec_scalar_equality(kind, spot, defer, service, hazard, n, seed):
     """``Simulator(..., vectorized=True)`` must replay the exact event
-    trajectory of the scalar reference: identical counters, summaries,
-    ledgers, and recorder cost cells within the documented <=1e-9 relative
-    tolerance (float reassociation on the vectorized sums), with recording
-    both off and on."""
+    trajectory of the scalar reference on the same jobs, ids included:
+    identical counters, summaries, ledgers, and recorder cost cells within
+    the documented <=1e-9 relative tolerance (float reassociation on the
+    vectorized sums), with recording both off and on."""
     for recording in (False, True):
+        ids = _rewind_ids()
         mv = _run_mode(kind, spot, defer, service, hazard, n, seed,
                        vectorized=True, recording=recording)
+        _rewind_ids(ids)
         ms = _run_mode(kind, spot, defer, service, hazard, n, seed,
                        vectorized=False, recording=recording)
         ss, sv = ms.summary(), mv.summary()
