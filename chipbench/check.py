@@ -55,6 +55,16 @@ class PackCall:
     demand: np.ndarray      # (T, F, R) rows the pack was given
     workloads: np.ndarray   # (T,)
     out: List[Tuple[int, List[int]]]  # (type, rows) per instance
+    job_tasks: np.ndarray   # (T,) live tasks of each row's job (job_tasks)
+
+
+def job_tasks(rp: np.ndarray, job_rp: Optional[np.ndarray]) -> np.ndarray:
+    """Live tasks of each pack row's job, from the RP sum over the job that
+    the pack was given: a job's tasks share their demand, so the sum is the
+    count times the row's own RP.  A pack given no job sums prices each
+    task as its own job (1)."""
+    jr = rp if job_rp is None else job_rp
+    return np.rint(np.asarray(jr) / np.asarray(rp)).astype(np.int64)
 
 
 Plan = List[Tuple[int, Tuple[int, ...]]]  # (type, task ids) per instance
@@ -123,18 +133,23 @@ class Sampler:
         return [kept[i] for i in sorted(kept)]
 
 
-def _match_rows(call: PackCall, demand: np.ndarray,
-                workloads: np.ndarray) -> Optional[np.ndarray]:
+def _match_rows(call: PackCall, demand: np.ndarray, workloads: np.ndarray,
+                sizes: np.ndarray) -> Optional[np.ndarray]:
     """Round rows of a pack call's rows: both are in ascending task id, so
-    the call's rows are a subsequence of the round's."""
+    the call's rows are a subsequence of the round's.  A row matches one of
+    the same workload and demand whose job has as many live tasks
+    (``call.job_tasks`` against ``sizes``, per round row): rows alike in
+    content but of jobs of other sizes carry other job RP sums."""
     if (len(call.workloads) == len(workloads)
             and np.array_equal(call.workloads, workloads)
-            and np.array_equal(call.demand, demand)):
+            and np.array_equal(call.demand, demand)
+            and np.array_equal(call.job_tasks, sizes)):
         return np.arange(len(workloads))
     out, j = [], 0
     for i in range(len(call.workloads)):
         while j < len(workloads) and not (
                 workloads[j] == call.workloads[i]
+                and sizes[j] == call.job_tasks[i]
                 and np.array_equal(demand[j], call.demand[i])):
             j += 1
         if j == len(workloads):
@@ -192,6 +207,11 @@ def check_round(rec: RoundRecord, cat: ref.Catalog, config: dict,
     row_of = {t: i for i, t in enumerate(ids)}
     rp = ref.reservation_prices(demand, cat)
     jrp = ref.job_sums(ts.job_ids, rp) if sc["multi_task_aware"] else rp
+    sizes = np.ones(len(ids), np.int64)  # live tasks of each row's job
+    if sc["multi_task_aware"]:
+        _, job_of, job_size = np.unique(ts.job_ids, return_inverse=True,
+                                        return_counts=True)
+        sizes = job_size[job_of]
     aware = sc["interference_aware"]
     tp = ref.Throughput(rec.entries if aware else {}, config["n_workloads"],
                         sc["default_t"] if aware else 1.0)
@@ -201,7 +221,7 @@ def check_round(rec: RoundRecord, cat: ref.Catalog, config: dict,
            "choices_not_judged": 0}
     packs = []  # per call: its placements as round rows
     for call in rec.packs:
-        rows = _match_rows(call, demand, workloads)
+        rows = _match_rows(call, demand, workloads, sizes)
         if rows is None:
             out["plan_violations"] += 1
             packs.append(None)

@@ -24,8 +24,8 @@ the limits of ``correct``).  One run, in this one process:
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
-window's first ``TRACE_SECONDS`` and the program's ``jax_pack`` spans in
-them.  Every metric is computed by
+window's first ``TRACE_SECONDS`` and the program's phase spans in them
+(``obs.profiler``; ``chipbench/spans.py``).  Every metric is computed by
 ``chipbench/metrics/<name>.py`` from the run's record.  ``--rehearse``
 runs every cell at a tiny fleet on the CPU, traced and not, and prints no
 result line.
@@ -56,6 +56,9 @@ TRACE_SECONDS = 10.0
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 KERNEL = "_pack_all_types"
 REHEARSAL_JOBS = 20  # live jobs of a CPU rehearsal's fleet
+#: largest relative gap between the host time per round read from the
+#: spans and from the host clock (``span_check``)
+SPAN_RTOL = 0.03
 
 
 def _paths() -> None:
@@ -127,8 +130,9 @@ def _wrap(orig, wrapper) -> None:
 
 
 class PackRecorder:
-    """While ``calls`` is a list, keeps each device pack's input rows and
-    placements (``pack_jax``) and the round's two candidate plans: the
+    """While ``calls`` is a list, keeps each device pack's input rows, its
+    reservation prices and job RP sums, and its placements (``pack_jax``),
+    and the round's two candidate plans: the
     configuration of the scheduler's own ``full_reconfiguration`` and of
     its ``partial_reconfiguration`` (a Full pack inside Partial's repack is
     no candidate)."""
@@ -148,7 +152,7 @@ class PackRecorder:
             if self.calls is not None:
                 b = sig.bind(*a, **kw).arguments
                 self.calls.append((b["demand_by_family"], b["workloads"],
-                                   out))
+                                   b["rp"], b["job_rp"], out))
             return out
 
         full_orig = inspect.unwrap(full_reconfiguration)
@@ -209,7 +213,7 @@ class Window:
         self.traced_rounds = 0  # the window's first rounds, in the profile
         self.unplaced = 0      # window rounds that left a live task out
         self.sampler = None
-        self.spans = None
+        self.profiler = None
         self._sim_note = contextlib.nullcontext()
 
     def _annotate(self, name: str):
@@ -219,7 +223,7 @@ class Window:
         return jax.profiler.TraceAnnotation(name)
 
     def round(self, view, plan):
-        from chipbench.check import PackCall, RoundRecord
+        from chipbench.check import PackCall, RoundRecord, job_tasks
         t0 = time.perf_counter()
         is_open = self.state == "open"
         if is_open:
@@ -245,7 +249,8 @@ class Window:
             self.sampler.offer(
                 len(self.round_s) - 1, len(view.tasks), len(calls) > 1,
                 lambda: RoundRecord(view, dict(entries),
-                                    [PackCall(d, w, o) for d, w, o in calls],
+                                    [PackCall(d, w, o, job_tasks(rp, jrp))
+                                     for d, w, rp, jrp, o in calls],
                                     list(cfg.assignments), full, partial,
                                     d_hat))
             if (self.tracing and t1 - self.t_open
@@ -291,7 +296,6 @@ class Window:
         jax.profiler.stop_trace()
         print(f"[chipbench] trace: {self.traced_rounds} rounds, written in "
               f"{time.perf_counter() - t_stop:.2f} s", file=sys.stderr)
-        self.spans = [s for s in self.profiler.spans if s.name == "jax_pack"]
 
     def _close(self, t1: float, sim_now: float) -> None:
         self.t_close = t1
@@ -471,17 +475,23 @@ def simulate(name: str, seed: int, seconds: float, traced: bool,
         "sim_hours": (window.sim_close - window.sim_open) / 3600.0,
         "setup_s": window.t_open - T_START,
         "compiles_in_window": window.compiles_window,
-        "pack_spans": None, "trace": None, "peaks": peaks,
+        "pack_spans": None, "spans": None, "trace": None, "peaks": peaks,
     }
     if traced:
         # the per-layer metrics read the traced rounds alone
         n = window.traced_rounds
         record.update(rounds=n, round_s=window.round_s[:n],
                       between_s=window.between_s[:n])
+        # every span the profiler closed while the trace ran, in its order
+        spans = window.profiler.spans
+        record["spans"] = [
+            {"name": s.name, "start_s": s.start_s, "duration_s": s.duration_s,
+             "parent": s.parent, "round": s.round, "tags": dict(s.tags)}
+            for s in spans]
         record["pack_spans"] = [
             {"n_tasks": s.tags.get("n_tasks"), "duration_s": s.duration_s,
              "max_fills": s.tags.get("max_fills")}
-            for s in window.spans]
+            for s in spans if s.name == "jax_pack"]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs),
               "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
@@ -525,6 +535,18 @@ def judge(run: dict, packer=None) -> dict:
     return numbers
 
 
+def span_check(rec: dict):
+    """Host time per round outside the device pack, read twice: from the
+    spans (each ``sched.round`` less the ``jax_pack`` spans) and from the
+    host clock (``sched_host_ms``: ``schedule()``'s wall less the same
+    ``jax_pack`` spans).  A span record that lost, doubled or cut short
+    rounds reads apart from the clock."""
+    from chipbench import spans
+    by_spans = (spans.ms_per_round(rec, "sched.round")
+                - spans.ms_per_round(rec, "jax_pack"))
+    return by_spans, reader("sched_host_ms")(rec)
+
+
 def run_cell(name: str, seed: int, seconds: float, traced: bool,
              rehearse: int = 0) -> dict:
     from chipbench import check
@@ -547,6 +569,15 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     q = np.percentile(record["round_s"], [50, 90, 95, 99]) * 1e3
     print(f"[chipbench] round ms p50/p90/p95/p99 {q.tolist()}",
           file=sys.stderr)
+    if traced:
+        by_spans, by_clock = span_check(record)
+        gap = abs(by_spans - by_clock) / by_clock
+        print(f"[chipbench] host ms/round outside jax_pack: spans "
+              f"{by_spans!r}, host clock {by_clock!r}; gap {gap!r} "
+              f"limit {SPAN_RTOL!r}", file=sys.stderr)
+        if not gap <= SPAN_RTOL:
+            sys.exit("chipbench: the span record disagrees with the host "
+                     "clock; no result")
     for k, v in shown.items():
         print(f"[chipbench] check {k}={v['value']!r} limit={v['limit']!r}",
               file=sys.stderr)

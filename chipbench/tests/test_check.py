@@ -33,7 +33,8 @@ def round_with_twins(plan_tail_type):
                              np.zeros(3, np.int64), demand)
     view = types.SimpleNamespace(
         tasks=ts, live=[types.SimpleNamespace(type_index=1, task_ids=(10, 11))])
-    repack = check.PackCall(demand[2:], np.zeros(1, np.int64), [(1, [0])])
+    repack = check.PackCall(demand[2:], np.zeros(1, np.int64), [(1, [0])],
+                            np.ones(1, np.int64))
     plan = [(1, (10, 11)), (plan_tail_type, (12,))]
     return check.RoundRecord(view, {}, [repack], plan)
 
@@ -50,6 +51,35 @@ def test_a_pending_twin_of_a_kept_task_is_no_violation(cat, config):
 def test_a_plan_that_is_not_the_pack_is_a_violation(cat, config):
     r = check.check_round(round_with_twins(0), cat, config)
     assert r["plan_violations"] == 1
+
+
+def round_with_jobs_of_two_sizes():
+    """Task 10 is a one-task job; tasks 11-14 are one four-task job alike
+    to it in workload and demand.  Partial repacks task 11 alone."""
+    demand = np.repeat(np.array([[1.0, 14.0, 196.3]] * 5)[:, None, :], 3,
+                       axis=1)
+    ts = TaskSet.from_arrays(np.arange(10, 15), np.array([1, 2, 2, 2, 2]),
+                             np.zeros(5, np.int64), demand)
+    rp = np.full(1, 3.06)
+    repack = check.PackCall(demand[1:2], np.zeros(1, np.int64), [(1, [0])],
+                            check.job_tasks(rp, 4 * rp))
+    return ts, repack
+
+
+def test_rows_are_matched_to_jobs_of_their_size():
+    ts, repack = round_with_jobs_of_two_sizes()
+    demand, workloads = ts.demand_by_family, ts.workloads
+    sizes = np.array([1, 4, 4, 4, 4])
+    assert check._match_rows(repack, demand, workloads,
+                             sizes).tolist() == [1]
+    # content alone (every size 1) takes the one-task job's row, whose job
+    # RP is a fourth
+    alone = check.PackCall(repack.demand, repack.workloads, repack.out,
+                           np.ones(1, np.int64))
+    assert check._match_rows(alone, demand, workloads,
+                             np.ones(5, np.int64)).tolist() == [0]
+    # a pack given no job sums prices each task as its own job
+    assert check.job_tasks(np.full(2, 3.06), None).tolist() == [1, 1]
 
 
 @pytest.mark.parametrize("shortfall, close", [(5e-6, True), (1e-4, False)])
