@@ -15,16 +15,26 @@ numbers come out:
 * ``pack_misplaced``: rows a pack places other than exactly once, plus its
   instances over capacity.
 * ``plan_violations``: live tasks the adopted plan places other than
-  exactly once; a plan that is neither of the round's two candidates;
+  exactly once; a planner given other prices than the reference's for the
+  round's time; a plan that is neither of the round's two candidates;
   a Full candidate that is not Full's pack; and in the Partial candidate,
   a live instance kept or evicted against the reference's keep test, a
   kept instance grown by best fit past its capacity or into a set the
   reference finds not cost-efficient, or a tail that is not Partial's
   pack.  A kept instance is not held to its capacity: the simulator leaves
   a task that is still launching where it was, so a live set can exceed it.
+  A round in which a policy layer forces instances out (``LAYERS``: spot
+  revocation notices) is a forced Partial: it counts one violation if the
+  scheduler did not force it, or forced one with nothing to force, and
+  otherwise its plan must be Partial's over the surviving instances, with
+  no forced-out instance kept; it has no Full candidate and no choice.
 * ``ensemble_wrong``: rounds whose adopted candidate is not the one the
   reference's ensemble picks from the two candidates' savings and
   migration costs (``reference.adopt_full``).
+
+Every round is held to its catalog at the round's time
+(``reference.Catalog.at``) where a layer plans at market prices: RP, job
+RP sums, the keep test, pack costs, S and M.
 
 Where the reference's pack meets a decision within a float32 band of its
 bar (``reference.BAND``), the rows of the instances from there on are not
@@ -48,6 +58,42 @@ CAP_RTOL = 1e-6
 #: keep-test verdicts within this relative margin of the bar are not judged
 #: (the program sums the same terms, possibly in another order)
 KEEP_RTOL = 1e-9
+#: relative slack of the price comparison: the same float64 path, computed
+#: apart (a stale grid point is a few percent away)
+PRICE_RTOL = 1e-12
+
+
+def _revoked(view) -> set:
+    return set(view.revoked or ())
+
+
+#: the policy layers the check holds a round to, by the class name in the
+#: configuration: whether the layer plans at the round's market prices, and
+#: the live instance ids it forces out of the round's plan
+LAYERS = {"SpotLayer": (True, _revoked)}
+
+
+def unknown_layers(config: dict) -> List[str]:
+    """The configuration's policy layers the check has no reference for."""
+    return [p["layer"] for p in config["scheduler"]["policies"]
+            if p["layer"] not in LAYERS]
+
+
+def round_catalog(cat: ref.Catalog, config: dict, t: float) -> ref.Catalog:
+    """The catalog a round at time ``t`` is held to: its market prices
+    where a layer plans at them, else the configuration's costs."""
+    if any(LAYERS[p["layer"]][0] for p in config["scheduler"]["policies"]):
+        return cat.at(t)
+    return cat
+
+
+def evacuated(config: dict, view) -> set:
+    """Live instance ids the configuration's layers force out of the
+    round's plan."""
+    out = set()
+    for p in config["scheduler"]["policies"]:
+        out |= LAYERS[p["layer"]][1](view)
+    return out
 
 
 @dataclasses.dataclass
@@ -79,33 +125,52 @@ class RoundRecord:
     full: Optional[Plan] = None     # the round's two candidates, where seen
     partial: Optional[Plan] = None
     d_hat_s: Optional[float] = None  # the scheduler's D at the round
+    forced: bool = False    # the scheduler forced a Partial this round
+    #: the hourly costs of the catalog each candidate was planned at
+    prices: List[np.ndarray] = dataclasses.field(default_factory=list)
 
 
 class Sampler:
     """Which window rounds are kept for the check, decided as they come so
     that the window keeps a handful of rounds and not all of them: the
-    round with the most tasks, and seeded reservoirs of ``n // 2`` rounds
-    that repacked and of the rest among all rounds."""
+    round with the most tasks, seeded reservoirs of ``n // 2`` rounds that
+    repacked and of the rest among all rounds, and one round of each of
+    ``STRATA`` that the window has, each taking its slot from the
+    all-rounds reservoir:
+
+    * ``pressure``: a round that the scheduler forced, or that holds an
+      instance under a revocation notice;
+    * ``full``: a round whose Full candidate the ensemble's own savings and
+      migration costs rate above Partial's (``reference.adopt_full`` on
+      them); in a sound run, a round that adopts Full.  Both are taken from
+      what the round reports, not from the verdict, so a scheduler that
+      never forces or never adopts Full still has such rounds checked."""
+
+    STRATA = ("pressure", "full")
 
     def __init__(self, n: int, seed: int):
         self.rng = np.random.default_rng([seed, 4])
+        self.rng_strata = np.random.default_rng([seed, 7])
         self.k_rep = n // 2
         self.k_all = max(n - self.k_rep - 1, 0)
         self.rep: List[Tuple[int, RoundRecord]] = []
         self.all: List[Tuple[int, RoundRecord]] = []
         self.seen_rep = self.seen_all = 0
         self.largest: Optional[Tuple[int, int, RoundRecord]] = None
+        self.strata = {k: [] for k in self.STRATA}
+        self.seen_strata = dict.fromkeys(self.STRATA, 0)
 
-    def _reservoir(self, res, k, seen, item) -> None:
+    def _reservoir(self, res, k, seen, item, rng=None) -> None:
         if len(res) < k:
             res.append(item)
         else:
-            j = int(self.rng.integers(seen))
+            j = int((rng or self.rng).integers(seen))
             if j < k:
                 res[j] = item
 
     def offer(self, index: int, n_tasks: int, repacked: bool,
-              make: Callable[[], RoundRecord]) -> None:
+              make: Callable[[], RoundRecord],
+              strata: Sequence[str] = ()) -> None:
         made = []
 
         def item():
@@ -118,8 +183,12 @@ class Sampler:
         if repacked:
             self.seen_rep += 1
             self._reservoir(self.rep, self.k_rep, self.seen_rep, None)
+        for k in strata:
+            self.seen_strata[k] += 1
+            self._reservoir(self.strata[k], 1, self.seen_strata[k], None,
+                            self.rng_strata)
         # fill the slots just admitted (the None placeholders)
-        for res in (self.all, self.rep):
+        for res in (self.all, self.rep, *self.strata.values()):
             for i, x in enumerate(res):
                 if x is None:
                     res[i] = item()
@@ -127,9 +196,20 @@ class Sampler:
             self.largest = (n_tasks,) + item()
 
     def records(self) -> List[RoundRecord]:
-        kept = {i: r for i, r in self.all + self.rep}
+        kept = dict(self.rep)
         if self.largest is not None:
             kept[self.largest[1]] = self.largest[2]
+        # a stratum's round not kept already takes one of the slots that
+        # the all-rounds reservoir adds, at random among the others
+        taken = {i: r for res in self.strata.values() for i, r in res
+                 if i not in kept}
+        slots = sum(1 for i, _ in self.all if i not in kept)
+        others = [(i, r) for i, r in self.all
+                  if i not in kept and i not in taken]
+        n = min(max(slots - len(taken), 0), len(others))
+        pick = self.rng_strata.choice(len(others), n, replace=False)
+        kept.update(taken)
+        kept.update(others[j] for j in pick.tolist())
         return [kept[i] for i in sorted(kept)]
 
 
@@ -198,8 +278,9 @@ Packer = Callable[..., List[Tuple[int, List[int]]]]
 
 def check_round(rec: RoundRecord, cat: ref.Catalog, config: dict,
                 packer: Optional[Packer] = None) -> Dict[str, float]:
-    """The numbers of one round.  ``packer`` replaces the program's pack
-    output by its own over the same rows (the control)."""
+    """The numbers of one round, held to ``cat``, the catalog of the
+    round's time (``round_catalog``).  ``packer`` replaces the program's
+    pack output by its own over the same rows (the control)."""
     sc = config["scheduler"]
     ts = rec.view.tasks
     demand, workloads = ts.demand_by_family, ts.workloads
@@ -218,7 +299,7 @@ def check_round(rec: RoundRecord, cat: ref.Catalog, config: dict,
     out = {"pack_cost_gap": 0.0, "pack_misplaced": 0, "pack_mismatched": 0,
            "rows_judged": 0, "rows_not_judged": 0, "plan_violations": 0,
            "ensemble_wrong": 0, "choices_judged": 0,
-           "choices_not_judged": 0}
+           "choices_not_judged": 0, "forced_checked": 0}
     packs = []  # per call: its placements as round rows
     for call in rec.packs:
         rows = _match_rows(call, demand, workloads, sizes)
@@ -248,6 +329,10 @@ def check_round(rec: RoundRecord, cat: ref.Catalog, config: dict,
         out["pack_mismatched"] += _mismatched(judged, got, want)
         out["rows_judged"] += len(judged)
     if packer is None:
+        out["plan_violations"] += sum(
+            int(len(c) != len(cat.costs)
+                or not np.allclose(c, cat.costs, rtol=PRICE_RTOL, atol=0.0))
+            for c in rec.prices)
         _plan_checks(rec, cat, config, demand, workloads, rp, jrp, tp,
                      row_of, packs, out)
     return out
@@ -259,6 +344,8 @@ def _plan_checks(rec, cat, config, demand, workloads, rp, jrp, tp, row_of,
     counts = collections.Counter(t for _, tids in plan for t in tids)
     bad = sum(1 for t in row_of if counts.get(t, 0) != 1)
     bad += sum(1 for t in counts if t not in row_of)
+    evac = evacuated(config, rec.view)
+    bad += int(bool(evac) != rec.forced)
     if bad:
         out["plan_violations"] += bad
         return
@@ -280,6 +367,14 @@ def _plan_checks(rec, cat, config, demand, workloads, rp, jrp, tp, row_of,
     repack = [p for call, p in zip(rec.packs, packs)
               if p is not None and len(call.workloads) != n_rows]
     full, partial = rec.full, rec.partial
+    if rec.forced:
+        # a forced Partial over the survivors: no Full candidate, no choice
+        partial = [(k, tuple(t)) for k, t in partial or plan]
+        bad = _partial_violations(rec, cat, demand, workloads, rp, jrp, tp,
+                                  row_of, partial, repack, canon, evac)
+        out["plan_violations"] += bad + int(plan != partial)
+        out["forced_checked"] += 1
+        return
     if full is None and partial is None:
         # the candidates were not seen: the plan is judged as the one it is
         if full_pack and canon(as_rows(plan)) == canon(full_pack[-1]):
@@ -293,7 +388,7 @@ def _plan_checks(rec, cat, config, demand, workloads, rp, jrp, tp, row_of,
     if partial is not None:
         partial = [(k, tuple(t)) for k, t in partial]
         bad += _partial_violations(rec, cat, demand, workloads, rp, jrp, tp,
-                                   row_of, partial, repack, canon)
+                                   row_of, partial, repack, canon, set())
     adopted_full = plan == full
     if not adopted_full and plan != partial:
         bad += 1
@@ -322,15 +417,25 @@ def _plan_checks(rec, cat, config, demand, workloads, rp, jrp, tp, row_of,
 
 
 def _partial_violations(rec, cat, demand, workloads, rp, jrp, tp, row_of,
-                        plan, repack, canon) -> int:
+                        plan, repack, canon, evac) -> int:
     """The Partial candidate against the keep test, best fit and its
-    repack: the kept instances in live order, then the repack's pack."""
+    repack: the kept instances in live order, then the repack's pack.  The
+    live instances in ``evac`` are forced out: each that the plan keeps
+    counts, and the keep test judges the others."""
     bad = 0
     plan_rows = [(k, [row_of[t] for t in tids]) for k, tids in plan]
     tail = repack[0] if repack else []
     head = plan[:len(plan) - len(tail)]
     if canon(plan_rows[len(head):]) != canon(tail):
         return 1
+    live = []
+    for inst in rec.view.live:
+        alive = tuple(t for t in inst.task_ids if t in row_of)
+        if not evac or inst.instance_id not in evac:
+            live.append((inst, alive))
+        elif alive:
+            bad += sum(1 for k, tids in head if k == inst.type_index
+                       and tids[:len(alive)] == alive)
 
     def verdict(rows, k) -> Optional[bool]:
         """Reference keep test: None where too close to the bar to judge."""
@@ -341,8 +446,7 @@ def _partial_violations(rec, cat, demand, workloads, rp, jrp, tp, row_of,
         return bool(s >= bar)
 
     j = 0
-    for inst in rec.view.live:
-        alive = tuple(t for t in inst.task_ids if t in row_of)
+    for inst, alive in live:
         if not alive:
             continue
         rows = [row_of[t] for t in alive]
@@ -361,18 +465,23 @@ def _partial_violations(rec, cat, demand, workloads, rp, jrp, tp, row_of,
 
 
 COUNTS = ("pack_misplaced", "plan_violations", "ensemble_wrong",
-          "rows_not_judged", "choices_judged", "choices_not_judged")
+          "rows_not_judged", "choices_judged", "choices_not_judged",
+          "forced_checked")
 
 
 def check_rounds(records: Sequence[RoundRecord], cat: ref.Catalog,
                  config: dict, packer: Optional[Packer] = None
                  ) -> Dict[str, float]:
-    """Worst gap, summed counts and the mismatch share over the rounds."""
+    """Worst gap, summed counts and the mismatch share over the rounds,
+    and how many distinct price lists they were held to."""
     out = dict.fromkeys(COUNTS, 0)
     out.update(pack_cost_gap=0.0, packs_checked=0, tasks_checked=0)
     mismatched = judged = 0
+    snapshots = set()
     for rec in records:
-        r = check_round(rec, cat, config, packer)
+        at = round_catalog(cat, config, rec.view.time)
+        snapshots.add(at.costs.tobytes())
+        r = check_round(rec, at, config, packer)
         out["pack_cost_gap"] = max(out["pack_cost_gap"], r["pack_cost_gap"])
         for k in COUNTS:
             out[k] += r[k]
@@ -381,6 +490,7 @@ def check_rounds(records: Sequence[RoundRecord], cat: ref.Catalog,
         out["packs_checked"] += len(rec.packs)
         out["tasks_checked"] += sum(len(c.workloads) for c in rec.packs)
     out["pack_mismatch_share"] = mismatched / max(judged, 1)
+    out["snapshots_checked"] = len(snapshots)
     return out
 
 

@@ -58,7 +58,7 @@ def main(argv=None) -> None:
                     help="faults of faults.py to plant, one after another")
     args = ap.parse_args(argv)
     run._paths()
-    from chipbench.faults import FAULTS
+    from chipbench.faults import FAULTS, SPOT_FAULTS
 
     def summary(fault, lines, upper):
         print(json.dumps({
@@ -78,7 +78,7 @@ def main(argv=None) -> None:
             saved.append((obj, name, getattr(obj, name)))
             setattr(obj, name, value)
 
-        FAULTS[fault](put)
+        {**FAULTS, **SPOT_FAULTS}[fault](put)
         try:
             lines = readings(args.workload, args.seeds, args.seconds,
                              control=False)

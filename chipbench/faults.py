@@ -4,13 +4,16 @@ Each entry of ``FAULTS`` takes a ``setattr``-like function (``setattr``
 itself, or pytest's ``monkeypatch.setattr``) and breaks the program through
 it: the device pack's answer altered where it is produced, half of the pack's
 rows left out, a round that returns its state unchanged, or an ensemble
-that adopts Partial whatever the two plans are worth.
+that adopts Partial whatever the two plans are worth.  ``SPOT_FAULTS``
+break what only a deployment under ``SpotLayer`` runs: rounds planned at
+the static on-demand prices, or an instance under a revocation notice kept.
 """
 import numpy as np
 
 from repro.core import engine_jax, scheduler
 from repro.core.cluster_types import ClusterConfig
 from repro.core.ensemble import EnsembleDecision
+from repro.policies import SpotLayer
 
 
 def _broken_pack(alter):
@@ -65,4 +68,12 @@ FAULTS = {
     "state_unchanged": lambda put: put(scheduler.EvaScheduler, "schedule",
                                        _unchanged),
     "always_partial": lambda put: put(scheduler, "choose", _always_partial),
+}
+
+
+SPOT_FAULTS = {
+    "static_prices": lambda put: put(
+        SpotLayer, "plan_catalog", lambda self, catalog, view, d: catalog),
+    "kept_revoked": lambda put: put(
+        SpotLayer, "evacuate", lambda self, raw, view: set()),
 }

@@ -23,6 +23,11 @@ Three things differ from the source, for a windowed run:
   cluster along its own path.
 * Job and task ids are numbered per call, so two runs build identical
   ids.
+
+A traffic file's ``arrivals`` is ``poisson`` (the times above) or
+``wave``: the same jobs and times, then each arrival moved to a seeded
+uniform instant in the first ``wave_width_s`` of its ``wave_period_s``,
+as work submitted on the hour arrives.
 """
 from __future__ import annotations
 
@@ -164,15 +169,34 @@ def jitter(jobs: List[Job], seed: int, block: int) -> List[Job]:
     return jobs
 
 
+def wave(jobs: List[Job], seed: int, period_s: float,
+         width_s: float) -> List[Job]:
+    """Hourly waves: every arrival moved to a uniform instant in the first
+    ``width_s`` of its ``period_s`` (the period its arrival time lies in).
+    The backlog (t=0), the jobs, their order and their ids stay."""
+    arriving = [j for j in jobs if j.arrival_time > 0.0]
+    rng = np.random.default_rng([seed, 6])
+    offset = rng.uniform(0.0, width_s, size=len(arriving))
+    for job, u in zip(arriving, offset):
+        start = np.floor(job.arrival_time / period_s) * period_s
+        job.arrival_time = float(start + u)
+    return jobs
+
+
+ARRIVALS = ("poisson", "wave")
+
+
 def make_jobs(config: dict, traffic: dict, seed: int,
               rate_scale: float = 1.0) -> List[Job]:
     """The whole trace of one run: the steady-state backlog at t=0, then
     ``arrival_jobs`` arrivals.  What the jobs are, and their order, comes
     from the configuration's ``content_seed``; ``seed`` moves their arrival
-    times (``jitter``).  ``rate_scale`` < 1 shrinks the rate and the backlog
-    alike (the CPU rehearsal's tiny fleet)."""
-    if traffic["arrivals"] != "poisson":
-        raise ValueError(f"unknown arrivals kind {traffic['arrivals']!r}")
+    times (``jitter``, then for ``wave`` traffic ``wave``).  ``rate_scale``
+    < 1 shrinks the rate and the backlog alike (the CPU rehearsal's tiny
+    fleet)."""
+    kind = traffic["arrivals"]
+    if kind not in ARRIVALS:
+        raise ValueError(f"unknown arrivals kind {kind!r}")
     shape = config["trace"]
     content = config["content_seed"]
     ids = _Ids()
@@ -184,7 +208,11 @@ def make_jobs(config: dict, traffic: dict, seed: int,
         n_arrivals = max(8, int(n_arrivals * rate_scale))
     arriving = arrivals(content, shape, n_arrivals,
                         config["mean_interarrival_s"] / rate_scale, ids)
-    return jitter(held + arriving, seed, traffic["gap_block"])
+    jobs = jitter(held + arriving, seed, traffic["gap_block"])
+    if kind == "wave":
+        jobs = wave(jobs, seed, traffic["wave_period_s"],
+                    traffic["wave_width_s"])
+    return jobs
 
 
 def task_pool(config: dict, seed: int, n_tasks: int) -> List[Task]:

@@ -5,6 +5,9 @@ It follows the paper (Eva, arXiv:2503.07437) and imports nothing of
 workloads and jobs, the live placements, the throughputs the monitor has
 observed so far, and the catalog as the configuration file states it.
 
+* ``Catalog``: the configuration's types; ``at(t)`` prices them at the
+  spot market the configuration states (``MeanReverting``), so a round is
+  held to the prices of its own time.
 * ``reservation_prices``: RP(task) is the hourly cost of the cheapest type
   whose capacity fits the task alone (section 4.2).
 * ``Throughput``: an observed co-location set's own entry, else the product
@@ -34,6 +37,7 @@ control computes the same in bfloat16.
 """
 from __future__ import annotations
 
+import copy
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -44,16 +48,68 @@ BAND = 256 * 2.0**-23
 CHOICE_BAND = 16 * 2.0**-23
 
 
+class MeanReverting:
+    """The configuration's spot market: an Ornstein-Uhlenbeck log price per
+    type around ``discount`` times the on-demand cost.  The path is drawn
+    once on a grid of ``step_s`` from ``default_rng(seed)``: x_0 = log
+    discount, x_i = x_{i-1} + reversion (mu - x_{i-1}) + volatility eps_i,
+    one standard normal eps per step and type.  A time takes the grid point
+    at or before it (the last one past ``horizon_s``), and the multiplier
+    exp(x) is held to [discount / 10, 1]: spot never costs more than on
+    demand."""
+
+    def __init__(self, n_types: int, discount: float, volatility: float,
+                 reversion: float, step_s: float, horizon_s: float,
+                 seed: int):
+        self.step_s = float(step_s)
+        n_steps = int(horizon_s / step_s) + 1
+        mu = np.log(discount)
+        eps = np.random.default_rng(seed).standard_normal(
+            (n_steps - 1, n_types))
+        x = np.empty((n_steps, n_types))
+        x[0] = mu
+        for i in range(1, n_steps):
+            x[i] = (x[i - 1] + reversion * (mu - x[i - 1])
+                    + volatility * eps[i - 1])
+        self.grid = np.clip(np.exp(x), discount / 10.0, 1.0)
+
+    def multipliers(self, t: float) -> np.ndarray:
+        i = min(int(max(t, 0.0) / self.step_s), len(self.grid) - 1)
+        return self.grid[i]
+
+
+#: the market kinds a configuration's ``market`` may name
+MARKETS = {"mean_reverting": MeanReverting}
+
+
 class Catalog:
     """The configuration's instance types: family index, (gpu, cpu, ram)
-    capacity and hourly cost of each."""
+    capacity and hourly cost of each; with a ``market``, the costs are its
+    on-demand base and ``at`` prices them at a time."""
 
-    def __init__(self, types: Sequence[dict], families: Sequence[str]):
+    def __init__(self, types: Sequence[dict], families: Sequence[str],
+                 market: Optional[dict] = None):
         self.names = [t["name"] for t in types]
         self.family = np.array([families.index(t["family"]) for t in types])
         self.caps = np.array([t["capacity"] for t in types], float)
         self.costs = np.array([t["hourly_cost"] for t in types], float)
         self.order = np.argsort(-self.costs, kind="stable")
+        self.market = None
+        if market is not None:
+            args = dict(market)
+            self.market = MARKETS[args.pop("price_model")](len(types),
+                                                           **args)
+
+    def at(self, t: float) -> "Catalog":
+        """The catalog at time ``t``'s market prices (itself without a
+        market), its types in descending cost again."""
+        if self.market is None:
+            return self
+        snap = copy.copy(self)
+        snap.market = None
+        snap.costs = self.costs * self.market.multipliers(t)
+        snap.order = np.argsort(-snap.costs, kind="stable")
+        return snap
 
 
 def reservation_prices(demand: np.ndarray, cat: Catalog) -> np.ndarray:

@@ -15,12 +15,14 @@ the limits of ``correct``).  One run, in this one process:
 3. builds the trace from ``--seed`` (``generator.py``);
 4. warms the cell's pack shapes through ``full_reconfiguration`` on task
    sets of the deployment's demand mix, then runs the program's own
-   ``Simulator.run`` loop with ``EvaScheduler`` on the device planner;
+   ``Simulator.run`` loop with ``EvaScheduler`` on the device planner,
+   under the deployment's price model and policy layers where it states
+   them (``build``);
    the cell's first ``setup_rounds`` rounds place the starting population;
 5. opens the window at the next round boundary and closes it at the first
    round boundary ``--seconds`` later;
-6. holds a sample of the window's rounds to the reference (``check.py``)
-   and prints one JSON line.
+6. holds a sample of the window's rounds to the reference (``check.py``),
+   each at its own time's market prices, and prints one JSON line.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
@@ -59,6 +61,9 @@ REHEARSAL_JOBS = 20  # live jobs of a CPU rehearsal's fleet
 #: largest relative gap between the host time per round read from the
 #: spans and from the host clock (``span_check``)
 SPAN_RTOL = 0.03
+#: the ``SimConfig`` settings a configuration's ``sim`` may state
+SIM_KEYS = {"price_update_interval_s", "preemption_notice_s",
+            "preemption_hazard_per_hour", "checkpoint_period_s"}
 
 
 def _paths() -> None:
@@ -135,13 +140,15 @@ class PackRecorder:
     and the round's two candidate plans: the
     configuration of the scheduler's own ``full_reconfiguration`` and of
     its ``partial_reconfiguration`` (a Full pack inside Partial's repack is
-    no candidate)."""
+    no candidate), with the hourly costs of the catalog each was given (the
+    array itself, not a copy)."""
 
     def __init__(self):
         from repro.core import (engine_jax, full_reconfiguration,
                                 partial_reconfiguration)
         self.calls = None
         self.full = self.partial = None
+        self.prices = []
         self.depth = 0
         orig = inspect.unwrap(engine_jax.pack_jax)
         sig = inspect.signature(orig)
@@ -157,12 +164,16 @@ class PackRecorder:
 
         full_orig = inspect.unwrap(full_reconfiguration)
         part_orig = inspect.unwrap(partial_reconfiguration)
+        full_sig = inspect.signature(full_orig)
+        part_sig = inspect.signature(part_orig)
 
         @functools.wraps(full_orig)
         def full(*a, **kw):
             cfg = full_orig(*a, **kw)
             if self.calls is not None and self.depth == 0:
                 self.full = list(cfg.assignments)
+                self.prices.append(
+                    full_sig.bind(*a, **kw).arguments["catalog"].costs)
             return cfg
 
         @functools.wraps(part_orig)
@@ -174,6 +185,8 @@ class PackRecorder:
                 self.depth -= 1
             if self.calls is not None:
                 self.partial = list(cfg.assignments)
+                self.prices.append(
+                    part_sig.bind(*a, **kw).arguments["catalog"].costs)
             return cfg
 
         _wrap(orig, pack_jax)
@@ -181,10 +194,10 @@ class PackRecorder:
         _wrap(part_orig, partial)
 
     def start(self) -> None:
-        self.calls, self.full, self.partial = [], None, None
+        self.calls, self.full, self.partial, self.prices = [], None, None, []
 
     def take(self):
-        out = self.calls, self.full, self.partial
+        out = self.calls, self.full, self.partial, self.prices
         self.calls = self.full = self.partial = None
         return out
 
@@ -210,6 +223,7 @@ class Window:
         self.done = 0          # rounds returned, set-up ones included
         self.state = "setup"   # -> "open" -> "closed"
         self.round_s, self.between_s = [], []
+        self.round_kinds = []  # "regular" / "forced", per window round
         self.traced_rounds = 0  # the window's first rounds, in the profile
         self.unplaced = 0      # window rounds that left a live task out
         self.sampler = None
@@ -224,6 +238,7 @@ class Window:
 
     def round(self, view, plan):
         from chipbench.check import PackCall, RoundRecord, job_tasks
+        from chipbench.reference import adopt_full
         t0 = time.perf_counter()
         is_open = self.state == "open"
         if is_open:
@@ -232,18 +247,30 @@ class Window:
             self.packs.start()
             estimator = getattr(self.sched, "estimator", None)
             d_hat = estimator.d_hat() if estimator is not None else None
+            forced_before = self.sched.forced_partials
+            decided_before = len(self.sched.decisions)
         n_compiled = self.compiles.n
         with self._annotate("chipbench.schedule"):
             cfg = plan(view)
         t1 = time.perf_counter()
         self.done += 1
         if is_open:
-            calls, full, partial = self.packs.take()
+            calls, full, partial, prices = self.packs.take()
             if self.compiles.n > n_compiled:
                 print(f"[chipbench] window round {len(self.round_s)} "
                       f"compiled: pack sizes {[len(c[1]) for c in calls]}",
                       file=sys.stderr)
             self.round_s.append(t1 - t0)
+            forced = self.sched.forced_partials > forced_before
+            self.round_kinds.append("forced" if forced else "regular")
+            strata = []
+            if forced or view.revoked:
+                strata.append("pressure")
+            if len(self.sched.decisions) > decided_before:
+                d = self.sched.decisions[-1]
+                if adopt_full(d.s_full, d.m_full, d.s_partial, d.m_partial,
+                              d.d_hat_s):
+                    strata.append("full")
             self.unplaced += _unplaced(view, cfg)
             entries = self.sched.table.entries  # not changed by schedule()
             self.sampler.offer(
@@ -252,7 +279,8 @@ class Window:
                                     [PackCall(d, w, o, job_tasks(rp, jrp))
                                      for d, w, rp, jrp, o in calls],
                                     list(cfg.assignments), full, partial,
-                                    d_hat))
+                                    d_hat, forced, prices),
+                strata)
             if (self.tracing and t1 - self.t_open
                     >= min(TRACE_SECONDS, self.seconds)):
                 self._stop_trace()
@@ -333,13 +361,25 @@ def _annotated_profiler():
 
 def build(config: dict, traffic: dict, seed: int, window: Window,
           rate_scale: float):
-    """The program's simulator and scheduler for this deployment."""
+    """The program's simulator and scheduler for this deployment.  Beyond
+    the catalog and the scheduler's switches, a configuration may state a
+    ``market`` (its price model by name and that model's arguments), the
+    spot settings of ``sim`` (``SIM_KEYS``), and ``scheduler.policies``,
+    each ``{"layer": <class in repro.policies>, "args": {...}}``."""
+    import repro.policies as policies_mod
     from repro.cluster import SimConfig, Simulator
     from repro.core import EvaScheduler, catalog as catalog_mod
 
-    from chipbench import generator
+    from chipbench import check, generator, reference
 
-    cat = getattr(catalog_mod, config["program_catalog"])()
+    market = dict(config.get("market") or {})
+    price_model = []
+    if market:
+        kind = market.pop("price_model")
+        if kind not in reference.MARKETS:
+            sys.exit(f"chipbench: no reference for market {kind!r}")
+        price_model.append(getattr(catalog_mod.PriceModel, kind)(**market))
+    cat = getattr(catalog_mod, config["program_catalog"])(*price_model)
     mine = [(t["name"], t["family"], tuple(t["capacity"]), t["hourly_cost"])
             for t in config["catalog"]]
     theirs = [(t.name, t.family, tuple(t.capacity), t.hourly_cost)
@@ -356,8 +396,21 @@ def build(config: dict, traffic: dict, seed: int, window: Window,
         sys.exit("chipbench: the program's migration delays differ from "
                  "the configuration's")
     sc = config["scheduler"]
-    if sc["policies"]:
-        sys.exit("chipbench: policy stacks are not wired yet")
+    layers = []
+    for p in sc["policies"]:
+        cls = getattr(policies_mod, p["layer"], None)
+        if not (isinstance(cls, type)
+                and issubclass(cls, policies_mod.PolicyLayer)):
+            sys.exit(f"chipbench: no policy layer {p['layer']!r} in "
+                     f"repro.policies")
+        layers.append(cls(**p.get("args", {})))
+    unjudged = check.unknown_layers(config)
+    if unjudged:
+        sys.exit(f"chipbench: the check has no reference for the policy "
+                 f"layers {unjudged}")
+    sim = config.get("sim", {})
+    if set(sim) - SIM_KEYS:
+        sys.exit(f"chipbench: unknown sim keys {sorted(set(sim) - SIM_KEYS)}")
     kw = {}
     if "engine" in inspect.signature(EvaScheduler.__init__).parameters:
         kw["engine"] = "jax"
@@ -368,14 +421,15 @@ def build(config: dict, traffic: dict, seed: int, window: Window,
 
     sched = TimedEva(cat, interference_aware=sc["interference_aware"],
                      multi_task_aware=sc["multi_task_aware"],
-                     mode=sc["mode"], default_t=sc["default_t"], **kw)
+                     mode=sc["mode"], default_t=sc["default_t"],
+                     **({"policies": layers} if layers else {}), **kw)
     window.sched = sched
     jobs = generator.make_jobs(config, traffic, seed, rate_scale)
     # the cloud's own draws (acquisition and setup delays) come from the
     # content seed too: every run gets the same delays, in its own order
     sim = Simulator(cat, jobs, sched, SimConfig(
         round_interval_s=config["round_interval_s"],
-        seed=config["content_seed"]))
+        seed=config["content_seed"], **sim))
     window.sim = sim
     return cat, sched, sim, kw
 
@@ -470,7 +524,7 @@ def simulate(name: str, seed: int, seconds: float, traced: bool,
     record = {
         "cell": name, "config": config,
         "rounds": len(window.round_s), "round_s": window.round_s,
-        "between_s": window.between_s,
+        "round_kinds": window.round_kinds, "between_s": window.between_s,
         "window_s": window.t_close - window.t_open,
         "sim_hours": (window.sim_close - window.sim_open) / 3600.0,
         "setup_s": window.t_open - T_START,
@@ -481,6 +535,7 @@ def simulate(name: str, seed: int, seconds: float, traced: bool,
         # the per-layer metrics read the traced rounds alone
         n = window.traced_rounds
         record.update(rounds=n, round_s=window.round_s[:n],
+                      round_kinds=window.round_kinds[:n],
                       between_s=window.between_s[:n])
         # every span the profiler closed while the trace ran, in its order
         spans = window.profiler.spans
@@ -528,7 +583,8 @@ def judge(run: dict, packer=None) -> dict:
     the control in the program's place."""
     from chipbench import check, reference
     config, records = run["config"], run["records"]
-    cat = reference.Catalog(config["catalog"], config["families"])
+    cat = reference.Catalog(config["catalog"], config["families"],
+                            config.get("market"))
     numbers = check.check_rounds(records, cat, config, packer)
     numbers["rounds_checked"] = len(records)
     numbers["unplaced_rounds"] = run["unplaced"]
@@ -561,7 +617,9 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
           f"task rows ({numbers['rows_not_judged']} not judged: past a "
           f"decision within the float32 band), "
           f"{numbers['choices_judged']} ensemble choices "
-          f"({numbers['choices_not_judged']} not judged) in "
+          f"({numbers['choices_not_judged']} not judged), "
+          f"{numbers['forced_checked']} forced rounds, "
+          f"{numbers['snapshots_checked']} price lists in "
           f"{time.perf_counter() - t_check:.2f} s; window "
           f"{run['attempted']} rounds, {record['sim_hours']:.3f} sim h, "
           f"{record['compiles_in_window']} compiles", file=sys.stderr)
@@ -569,6 +627,14 @@ def run_cell(name: str, seed: int, seconds: float, traced: bool,
     q = np.percentile(record["round_s"], [50, 90, 95, 99]) * 1e3
     print(f"[chipbench] round ms p50/p90/p95/p99 {q.tolist()}",
           file=sys.stderr)
+    kinds = np.array(record["round_kinds"])
+    if np.any(kinds == "forced"):
+        for kind in ("regular", "forced"):
+            ms = np.asarray(record["round_s"])[kinds == kind] * 1e3
+            if ms.size:
+                print(f"[chipbench] {kind} rounds {ms.size}, ms p50/p95 "
+                      f"{np.percentile(ms, [50, 95]).tolist()}",
+                      file=sys.stderr)
     if traced:
         by_spans, by_clock = span_check(record)
         gap = abs(by_spans - by_clock) / by_clock

@@ -128,3 +128,75 @@ def test_migration_cost_prices_moves_and_launches(cat):
 ])
 def test_the_ensemble_adopts_full_when_it_is_worth_more(values, want):
     assert ref.adopt_full(*values) == want
+
+
+def test_a_planner_given_other_prices_is_a_violation(cat, config):
+    rec = round_with_twins(1)
+    rec.prices = [cat.costs.copy()]
+    assert check.check_round(rec, cat, config)["plan_violations"] == 0
+    rec.prices = [cat.costs * (1 + 1e-6)]
+    assert check.check_round(rec, cat, config)["plan_violations"] == 1
+
+
+def forced_round(plan, packed, forced=True, revoked=(9,)):
+    """Instance 7 (type 1) holds tasks 10 and 11 and is kept; instance 9
+    (type 1) holds task 12 and is under a revocation notice.  ``packed``:
+    Partial repacked task 12 onto a fresh instance."""
+    rec = round_with_twins(1)
+    rec.view = types.SimpleNamespace(
+        tasks=rec.view.tasks, revoked=set(revoked),
+        live=[types.SimpleNamespace(instance_id=7, type_index=1,
+                                    task_ids=(10, 11)),
+              types.SimpleNamespace(instance_id=9, type_index=1,
+                                    task_ids=(12,))])
+    if not packed:
+        rec.packs = []
+    rec.plan = rec.partial = plan
+    rec.forced = forced
+    return rec
+
+
+@pytest.fixture(scope="module")
+def spot_config(config):
+    return dict(config, scheduler=dict(
+        config["scheduler"], policies=[{"layer": "SpotLayer", "args": {}}]))
+
+
+@pytest.mark.parametrize("rec, violations, judged", [
+    (forced_round([(1, (10, 11)), (1, (12,))], packed=True), 0, 1),
+    # the instance under notice kept (and the keep test's walk over the
+    # survivors finds a slot too many), its task not repacked
+    (forced_round([(1, (10, 11)), (1, (12,))], packed=False), 2, 1),
+    # a notice the scheduler did not force a Partial for
+    (forced_round([(1, (10, 11)), (1, (12,))], packed=True, forced=False),
+     1, 0),
+    # a forced Partial with no notice
+    (forced_round([(1, (10, 11)), (1, (12,))], packed=True, revoked=()),
+     1, 0),
+])
+def test_a_forced_round_is_partial_over_the_survivors(cat, spot_config, rec,
+                                                      violations, judged):
+    r = check.check_round(rec, cat, spot_config)
+    assert r["plan_violations"] == violations
+    assert r["forced_checked"] == judged
+    assert r["choices_judged"] + r["choices_not_judged"] == 0
+
+
+@pytest.mark.parametrize("repacked, exact", [
+    # rounds kept twice (the largest also drawn to repack) leave fewer
+    (lambda i: i % 3 == 0, False),
+    # four repacking rounds, one of them the ``full`` stratum's: the
+    # repacking reservoir holds it already, so it takes no slot
+    (lambda i: i in (0, 50, 100, 133), True),
+])
+def test_the_strata_take_their_rounds_from_the_all_rounds_reservoir(
+        repacked, exact):
+    for seed in range(5):
+        s = check.Sampler(8, seed)
+        for i in range(200):
+            strata = (["pressure"] if i == 57 else []) + (
+                ["full"] if i == 133 else [])
+            s.offer(i, 10 + (i == 90), repacked(i), lambda i=i: i, strata)
+        got = s.records()
+        assert {57, 133, 90} | {i for i, _ in s.rep} <= set(got)
+        assert len(got) == 8 if exact else len(got) <= 8

@@ -12,8 +12,8 @@ CELLS = ["fleet1k-steady", "paper-steady"]
 SEED = 2**35 + 11
 
 
-def correct_of(name, seconds=1.5):
-    r = run.run_cell(name, SEED, seconds, traced=False,
+def correct_of(name, seconds=1.5, seed=SEED):
+    r = run.run_cell(name, seed, seconds, traced=False,
                      rehearse=run.REHEARSAL_JOBS)
     return r["correct"], {k: v["value"] for k, v in r["checks"].items()}
 
@@ -38,4 +38,15 @@ def test_control_fails(name):
 def test_broken_path_is_not_correct(monkeypatch, name, fault):
     FAULTS[fault](monkeypatch.setattr)
     ok, numbers = correct_of(name)
+    assert not ok, numbers
+
+
+@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("seed", [2**35 + 31, 2**35 + 32, 2**35 + 33])
+def test_always_partial_is_not_correct_on_any_seed(monkeypatch, name, seed):
+    """The sample holds a round whose Full candidate the ensemble's own
+    values rate higher, so an ensemble that never adopts Full is caught
+    whichever rounds the seed draws."""
+    FAULTS["always_partial"](monkeypatch.setattr)
+    ok, numbers = correct_of(name, seed=seed)
     assert not ok, numbers

@@ -1,4 +1,5 @@
 """The traffic generator is tied to the program's trace generator."""
+import hashlib
 import json
 import os
 
@@ -98,3 +99,69 @@ def test_seeds_move_only_the_arrival_times():
     # every block of 8 arrivals ends at the same instant
     np.testing.assert_allclose(ta[7::8], tb[7::8], rtol=1e-12)
     assert np.all(np.diff(ta) >= 0) and np.all(ta > 0)
+
+
+def traffic(name):
+    with open(os.path.join(os.path.dirname(CONFIGS), "traffic",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def digest(jobs):
+    """Every job's ids, workload, size, arrival, duration and demands."""
+    h = hashlib.sha256()
+    for j in jobs:
+        h.update(np.array([j.job_id, j.workload, j.n_tasks],
+                          np.int64).tobytes())
+        h.update(np.array([j.arrival_time, j.duration_s],
+                          np.float64).tobytes())
+        for t in j.tasks:
+            h.update(np.array([t.task_id, t.job_id, t.workload],
+                              np.int64).tobytes())
+            h.update(np.array([t.demands[f] for f in generator.FAMILIES],
+                              np.float64).tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed, want", [
+    (0, "cadccc72d8081dc1bee83fba0d0d6d48b3ad5d84ae86b41a54b46330e55630ca"),
+    (7, "b903c4b4dc7a08435d73bbd546c9147ef25ec6a597c7648e76d77c406019774d"),
+    (2**40 + 3,
+     "f3d6470be8f5b2d3874a572905a748e7b9d473be518cb438d9989383340c689b"),
+])
+def test_steady_is_unchanged_bit_for_bit(seed, want):
+    """The digests of ``steady``'s whole trace before the ``wave`` kind
+    was added."""
+    jobs = generator.make_jobs(load("eva-alibaba-1k"), traffic("steady"),
+                               seed)
+    assert digest(jobs) == want
+
+
+@pytest.mark.parametrize("seed", [3, 2**33 + 1])
+def test_wave_arrivals_lie_in_the_first_minutes_of_their_hour(seed):
+    wave = traffic("wave")
+    period, width = wave["wave_period_s"], wave["wave_width_s"]
+    jobs = generator.make_jobs(load("eva-alibaba-1k"), wave, seed)
+    t = np.array([j.arrival_time for j in jobs if j.arrival_time > 0.0])
+    hour = np.floor(t / period)
+    assert np.all(t >= hour * period) and np.all(t < hour * period + width)
+    # the waves spread over their window: every hour of the trace has one
+    assert len(np.unique(hour)) == int(hour.max()) + 1
+
+
+@pytest.mark.parametrize("seed", [3, 2**33 + 1])
+def test_wave_carries_steadys_jobs_from_steadys_hours(seed):
+    cfg = load("eva-alibaba-1k")
+    wave = traffic("wave")
+    steady = generator.make_jobs(cfg, traffic("steady"), seed)
+    waved = generator.make_jobs(cfg, wave, seed)
+    assert [content(j) for j in waved] == [content(j) for j in steady]
+    assert [(j.job_id, [t.task_id for t in j.tasks]) for j in waved] == [
+        (j.job_id, [t.task_id for t in j.tasks]) for j in steady]
+    period = wave["wave_period_s"]
+    for a, b in zip(steady, waved):
+        if a.arrival_time == 0.0:  # the backlog stays at t=0
+            assert b.arrival_time == 0.0
+        else:
+            assert (np.floor(b.arrival_time / period)
+                    == np.floor(a.arrival_time / period))
