@@ -15,7 +15,7 @@ the per-type engine used, with two fleet-scale upgrades:
 * **Class collapse.**  Tasks with identical (workload, RP, job-RP, demand)
   are interchangeable to Algorithm 1, so the argmax runs over the C ≤ ~tens
   of distinct *classes* with multiplicity counts, not the T tasks — each
-  greedy step is O(C + W²) regardless of fleet size.  When the pairwise
+  greedy step is O(C·W + W²) regardless of fleet size.  When the pairwise
   matrix is all-ones (interference-oblivious packs) classes additionally
   merge across workloads with equal price/demand rows.
 * **Single jitted multi-type pass.**  The whole descending-cost type loop —
@@ -123,16 +123,24 @@ def _pack_all_types(cdemand, cw, crp, cjr, counts0, rows_pad, P, logP,
                     costs, caps, fams, rids, budget, *, max_fills: int):
     """One fused pass over every (masked-in) type in descending-cost order.
 
-    Shapes: cdemand (C,F,R) · cw/crp/cjr/counts0 (C,) · rows_pad (C,M) ·
+    Shapes: cdemand (F,R,C) · cw/crp/cjr/counts0 (C,) · rows_pad (C,M) ·
     P/logP (W,W) · costs/fams/rids (K,) · caps (K,R) · budget (NR,).
     Returns the final budget plus ``max_fills``-bounded fill records
-    (type position, replication, per-class composition) and an overflow
+    (type position, replication, per-class composition), an overflow
     flag (caller retries with a larger buffer — record count is bounded by
-    the task count, so the retry always terminates).
+    the task count, so the retry always terminates) and two counts: the
+    greedy steps evaluated (each adds a task or ends its fill) and the
+    fills attempted.
+
+    Every per-class operand of a greedy step is dense over the class axis,
+    which runs along the vector lanes: the demands are class-minor, the
+    running log-throughput update is a row of the class-major table
+    ``logPc``, and the tie key (each class's current lowest task row) is
+    carried across steps and fills instead of gathered from ``rows_pad``.
     """
     C = cw.shape[0]
+    M = rows_pad.shape[1]
     W = P.shape[0]
-    K = costs.shape[0]
     dt = crp.dtype
     arange_c = jnp.arange(C)
     # complement interference matrix: the multi-task member penalty is
@@ -140,6 +148,12 @@ def _pack_all_types(cdemand, cw, crp, cjr, counts0, rows_pad, P, logP,
     # interference is off (P ≡ 1) instead of carrying the reduction-order
     # residual of agg.sum() − (agg @ P)[c]
     Q = 1.0 - P
+    # logPc[w, c] = log P[w_c, w]: adding a task of workload w multiplies
+    # every candidate class's throughput by P[w_c, w]
+    logPc = logP.T[:, cw]
+    # of_w[w, c] = (w_c == w): a class's entry of a per-workload vector is
+    # the sum down its column of this mask, exact (one term is not zero)
+    of_w = jnp.arange(W)[:, None] == cw[None, :]
     # break-even acceptance: fills on a task's RP type sum exactly to the
     # instance cost under the catalog's linear pricing, so the gate needs a
     # tolerance matched to the accumulator dtype — f32 greedy sums drift
@@ -149,64 +163,77 @@ def _pack_all_types(cdemand, cw, crp, cjr, counts0, rows_pad, P, logP,
     # demands (e.g. 173.6 GB) leave f32 residuals below an exact fit
     rtol = dt.type(256 * jnp.finfo(dt).eps)
 
-    def fill_one(counts, d, cap0):
-        """Greedy-fill one fresh instance; returns (used, tnrp, had_tie)."""
+    def row_at(c, j):
+        """rows_pad[c, min(j, M − 1)] for scalar c, j."""
+        return jax.lax.dynamic_slice(
+            rows_pad, (c, jnp.minimum(j, M - 1)), (1, 1))[0, 0]
+
+    def fill_one(counts, d, cap0, rowkey, picks):
+        """Greedy-fill one fresh instance; returns (used, tnrp, had_tie,
+        rowkey, picks) with the tie key advanced past the used rows."""
         fit_tol = _EPS + rtol * cap0
 
         def cond(s):
             return ~s[-1]
 
         def body(s):
-            used, capr, logtput, agg, cur, tie, _ = s
+            used, capr, logtput, agg, cur, tie, rowkey, picks, _ = s
             feas = ((counts - used) > 0) & jnp.all(
-                d <= capr[None, :] + fit_tol[None, :], axis=1)
+                d <= (capr + fit_tol)[:, None], axis=0)
             cand_tput = jnp.exp(logtput)
             # full f32 passes: at default precision the TPU's MXU rounds
             # through bf16, which can flip the argmax under interference
             qvec = jnp.matmul(agg, Q, precision=jax.lax.Precision.HIGHEST)
-            score = cur - qvec[cw] + crp - (1.0 - cand_tput) * cjr
+            qvec_c = jnp.where(of_w, qvec[:, None], dt.type(0)).sum(axis=0)
+            score = cur - qvec_c + crp - (1.0 - cand_tput) * cjr
             masked = jnp.where(feas, score, dt.type(_NEG))
             mx = masked.max()
             ok = feas.any() & (mx >= cur - _EPS)
             at_max = feas & (masked == mx)
             crosstie = at_max.sum() > 1
-            # current lowest task row per class = numpy's first-max tie-break
-            ptr = counts0 - counts + used
-            rowkey = rows_pad[arange_c,
-                              jnp.minimum(ptr, rows_pad.shape[1] - 1)]
+            # lowest current task row among the tied = numpy's first max
             best = jnp.argmin(jnp.where(at_max, rowkey, _BIG_I))
+            best = best.astype(jnp.int32)
+            hit = arange_c == best
             wb = cw[best]
             tput_b = cand_tput[best]
-            n_used = used.at[best].add(1)
-            n_capr = capr - d[best]
-            n_logtput = logtput + logP[cw, wb]
+            ptr_b = counts0[best] - counts[best] + used[best]
+            n_used = jnp.where(hit, used + 1, used)
+            n_capr = capr - d[:, best]
+            n_logtput = logtput + logPc[wb]
             n_agg = (agg * P[:, wb]).at[wb].add(cjr[best] * tput_b)
+            n_rowkey = jnp.where(hit, row_at(best, ptr_b + 1), rowkey)
             used = jnp.where(ok, n_used, used)
             capr = jnp.where(ok, n_capr, capr)
             logtput = jnp.where(ok, n_logtput, logtput)
             agg = jnp.where(ok, n_agg, agg)
             cur = jnp.where(ok, mx, cur)
             tie = tie | (crosstie & ok)
-            return (used, capr, logtput, agg, cur, tie, ~ok)
+            rowkey = jnp.where(ok, n_rowkey, rowkey)
+            return (used, capr, logtput, agg, cur, tie, rowkey, picks + 1,
+                    ~ok)
 
         init = (jnp.zeros(C, jnp.int32), cap0, jnp.zeros(C, dt),
-                jnp.zeros(W, dt), jnp.zeros((), dt),
-                jnp.asarray(False), jnp.asarray(False))
-        used, _, _, _, cur, tie, _ = jax.lax.while_loop(cond, body, init)
-        return used, cur, tie
+                jnp.zeros(W, dt), jnp.zeros((), dt), jnp.asarray(False),
+                rowkey, picks, jnp.asarray(False))
+        used, _, _, _, cur, tie, rowkey, picks, _ = jax.lax.while_loop(
+            cond, body, init)
+        return used, cur, tie, rowkey, picks
 
     def type_body(t, st):
         cost = costs[t]
         cap0 = caps[t]
         rid = rids[t]
-        d = jnp.take(cdemand, fams[t], axis=1)  # (C, R) on this family
+        d = cdemand[fams[t]]  # (R, C) on this family
 
         def fcond(s):
             return s[-1]
 
         def fbody(s):
-            counts, budget, rt, rr, rc, n_rec, ovf, _ = s
-            used, cur, had_tie = fill_one(counts, d, cap0)
+            (counts, budget, rowkey, rt, rr, rc, n_rec, ovf, picks, fills,
+             _) = s
+            used, cur, had_tie, filled_key, picks = fill_one(
+                counts, d, cap0, rowkey, picks)
             accept = ((used.sum() > 0)
                       & (cur >= cost - _EPS - rtol * cost)
                       & (budget[rid] > 0))
@@ -224,21 +251,32 @@ def _pack_all_types(cdemand, cw, crp, cjr, counts0, rows_pad, P, logP,
             ovf = ovf | (accept & ~can)
             counts = jnp.where(accept, counts - rep * used, counts)
             budget = jnp.where(accept, budget.at[rid].add(-rep), budget)
+            # a rejected fill leaves every pointer where it was; a
+            # replicated one moves them past rep copies, so its key is
+            # read afresh: each class's lowest row not yet placed
+            rowkey = jax.lax.cond(
+                accept & (rep > 1),
+                lambda: rows_pad[arange_c,
+                                 jnp.minimum(counts0 - counts, M - 1)],
+                lambda: jnp.where(accept, filled_key, rowkey))
             go = accept & (counts > 0).any()
-            return (counts, budget, rt, rr, rc, n_rec, ovf, go)
+            return (counts, budget, rowkey, rt, rr, rc, n_rec, ovf, picks,
+                    fills + 1, go)
 
         counts = st[0]
         init = st + ((counts > 0).any(),)
         return jax.lax.while_loop(fcond, fbody, init)[:-1]
 
-    rec_type = jnp.full((max_fills,), -1, jnp.int32)
-    rec_rep = jnp.zeros((max_fills,), jnp.int32)
-    rec_comp = jnp.zeros((max_fills, C), jnp.int32)
-    st = (counts0, budget, rec_type, rec_rep, rec_comp,
-          jnp.zeros((), jnp.int32), jnp.asarray(False))
-    st = jax.lax.fori_loop(0, K, type_body, st)
-    _, budget, rec_type, rec_rep, rec_comp, n_rec, overflow = st
-    return budget, rec_type, rec_rep, rec_comp, n_rec, overflow
+    zero = jnp.zeros((), jnp.int32)
+    st = (counts0, budget, rows_pad[:, 0],
+          jnp.full((max_fills,), -1, jnp.int32),
+          jnp.zeros((max_fills,), jnp.int32),
+          jnp.zeros((max_fills, C), jnp.int32), zero, jnp.asarray(False),
+          zero, zero)
+    st = jax.lax.fori_loop(0, costs.shape[0], type_body, st)
+    _, budget, _, rec_type, rec_rep, rec_comp, n_rec, overflow, picks, \
+        fills = st
+    return budget, rec_type, rec_rep, rec_comp, n_rec, overflow, picks, fills
 
 
 def pack_jax(demand_by_family: np.ndarray, workloads: np.ndarray,
@@ -278,6 +316,7 @@ def pack_jax(demand_by_family: np.ndarray, workloads: np.ndarray,
         cjr_p = np.concatenate([cjr, np.zeros(pad)]).astype(dt)
         cdem_p = np.concatenate(
             [cdemand, np.zeros((pad,) + cdemand.shape[1:])]).astype(dt)
+        cdem_p = np.ascontiguousarray(cdem_p.transpose(1, 2, 0))  # (F,R,C)
 
         ks = [k for k in catalog.order_desc.tolist()
               if type_mask is None or bool(np.asarray(type_mask)[k])]
@@ -306,20 +345,21 @@ def pack_jax(demand_by_family: np.ndarray, workloads: np.ndarray,
         # unless a profiler was activated; the bool(overflow) host sync sits
         # inside the span so device time is part of the measurement
         with _prof.span("jax_pack") as sp:
-            budget_out, rec_type, rec_rep, rec_comp, n_rec, overflow = \
-                _pack_all_types(jnp.asarray(cdem_p), jnp.asarray(cw_p),
-                                jnp.asarray(crp_p), jnp.asarray(cjr_p),
-                                jnp.asarray(counts_p), jnp.asarray(rows_pad),
-                                P, logP, jnp.asarray(costs),
-                                jnp.asarray(caps), jnp.asarray(fams),
-                                jnp.asarray(rids), jnp.asarray(budget0),
-                                max_fills=max_fills)
+            (budget_out, rec_type, rec_rep, rec_comp, n_rec, overflow,
+             picks, fills) = _pack_all_types(
+                jnp.asarray(cdem_p), jnp.asarray(cw_p), jnp.asarray(crp_p),
+                jnp.asarray(cjr_p), jnp.asarray(counts_p),
+                jnp.asarray(rows_pad), P, logP, jnp.asarray(costs),
+                jnp.asarray(caps), jnp.asarray(fams), jnp.asarray(rids),
+                jnp.asarray(budget0), max_fills=max_fills)
             overflowed = bool(overflow)
         if sp is not None:  # jit-cache growth == this call compiled
             sp.tags["stage"] = ("compile" if cache_size() > n_cached
                                 else "execute")
             sp.tags["max_fills"] = max_fills
             sp.tags["n_tasks"] = T
+            sp.tags["picks"] = int(picks)
+            sp.tags["fills"] = int(fills)
         if not overflowed:
             break
         max_fills *= 2

@@ -48,7 +48,8 @@ SPANS: Dict[str, str] = {
                     "sums, pairwise matrix; pack_jax's class collapse and "
                     "padded arrays; tag classes",
     "jax_pack": "pack_jax's device call: upload, device work and the "
-                "overflow sync; tags stage, max_fills, n_tasks",
+                "overflow sync; tags stage, max_fills, n_tasks, and the "
+                "call's greedy steps (picks) and fills attempted (fills)",
     "pack.readback": "pack_jax: fill records to the host, expanded to task "
                      "rows; tag records",
     "sim.view": "Simulator: throughput reports and the round's "

@@ -252,3 +252,136 @@ def test_table3_walkthrough_jax_engine():
     cfg = full_reconfiguration(ts, cat, None, interference_aware=False,
                                multi_task_aware=False, engine="jax")
     assert cfg.total_hourly_cost(cat) == pytest.approx(12.8)
+
+
+def _tie_fleet(seed, interference):
+    """~1,000 task classes built for exact cross-class score ties.
+
+    RPs and job-RP sums sit on a coarse dyadic grid shared by every
+    workload, so many classes of one workload score exactly alike and the
+    tie goes to the lowest current task row; demands are distinct eighths,
+    so each task is its own class, except for multi-task classes of 2-12
+    identical tasks whose rows are scattered among the others.  Their
+    row pointers advance inside a fill, stay put across a rejected one and
+    jump over replicated fills, so every path of the tie key is taken.
+    Every value is exact in float32, as are its sums."""
+    rng = np.random.default_rng(seed)
+    n_single, n_multi = 900, 40
+    W, F = NUM_WORKLOADS, len(FAMILIES)
+    gpu = rng.integers(1, 3, n_single + n_multi)
+    demand = np.zeros((n_single + n_multi, F, 3))
+    demand[:, 0] = np.column_stack(
+        [gpu, rng.integers(8, 97, gpu.size) / 8,
+         rng.integers(32, 481, gpu.size) / 8])
+    for f in (1, 2):  # CPU families: no GPU, the task's host share
+        demand[:, f, 1] = rng.integers(8, 65, gpu.size) / 8
+        demand[:, f, 2] = rng.integers(16, 513, gpu.size) / 8
+    workloads = rng.integers(4, size=gpu.size)  # big same-workload ties
+    rp = (2 + rng.integers(6, size=gpu.size)) / 4
+    jr = rp * rng.integers(1, 3, size=gpu.size)
+    copies = np.concatenate([np.ones(n_single, int),
+                             rng.integers(2, 13, n_multi)])
+    rows = list(rng.permutation(np.repeat(np.arange(gpu.size), copies)))
+    # A replicated fill, then a tie across it, at fixed rows: X (RP 5.5, 7
+    # tasks) and Z (RP 2.75, 9 tasks) outbid every other task, and two of
+    # each fill the dearest type with no tie (Z2, Z's equal on 6 GPUs,
+    # fits only beside one X), so the fill is emitted 3 times; the fourth
+    # takes the last X, then Z2 (row 65) ties Z, whose lowest row left is
+    # 70, not the 30 the fill's own picks reached: X and Z2 fill it.
+    placed = []
+    for rp_s, p3, at in ((5.5, (2, 8, 200), (5, 15, 25, 35, 45, 55, 75)),
+                         (2.75, (2, 8, 50), (10, 20, 30, 40, 50, 60, 70, 80,
+                                             90)),
+                         (2.75, (6, 8, 50), (65,))):
+        demand = np.concatenate([demand, [[p3, (0, 64, 512),
+                                           (0, 64, 512)]]])
+        workloads = np.append(workloads, 0)
+        rp = np.append(rp, rp_s)
+        jr = np.append(jr, rp_s)
+        placed += [(r, len(rp) - 1) for r in at]
+    for r, task in sorted(placed):
+        rows.insert(r, task)
+    cat = Catalog.from_types([
+        InstanceType("g8", "p3", (8, 64, 512), 8.0),
+        InstanceType("g4", "p3", (4, 32, 256), 4.0),
+        InstanceType("g1", "p3", (1, 8, 64), 1.25),
+        InstanceType("c32", "c7i", (0, 32, 64), 2.0),
+        InstanceType("r32", "r7i", (0, 32, 256), 3.0),
+        InstanceType("r8", "r7i", (0, 8, 64), 0.5),
+    ])
+    pairwise = np.ones((W, W))
+    if interference:
+        pairwise = rng.uniform(0.9, 1.0, (W, W))
+        np.fill_diagonal(pairwise, 1.0)
+    return (demand[rows], workloads[rows], rp[rows], jr[rows], cat,
+            pairwise)
+
+
+@pytest.mark.parametrize("interference,x64", [(False, False), (False, True),
+                                              (True, True)],
+                         ids=["off-f32", "off-x64", "on-x64"])
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_jax_matches_numpy_pick_for_pick_at_fleet_width(seed, interference,
+                                                        x64):
+    """~1,000 classes, the device's 1,024-class bucket: the same instances
+    in the same order with the same tasks as numpy's task-level argmax,
+    through ties that cross accepted, rejected and replicated fills.
+    Interference is compared under x64, where both engines sum alike;
+    without it every score is exact in float32 too."""
+    import jax
+
+    from repro.core.engine_jax import pack_jax
+    from repro.core.full_reconfig import _pack_numpy
+    demand, workloads, rp, jr, cat, pairwise = _tie_fleet(seed, interference)
+    assert 900 < len(np.unique(demand.reshape(len(rp), -1), axis=0)) <= 1024
+    want = _pack_numpy(demand, workloads, rp, jr, cat, pairwise)
+    jax.config.update("jax_enable_x64", x64)
+    try:
+        got = pack_jax(demand, workloads, rp, jr, cat, pairwise)
+    finally:
+        jax.config.update("jax_enable_x64", False)
+    assert sum(len(r) for _, r in want) == len(rp)  # every task placed
+    assert [(k, sorted(r)) for k, r in got] == \
+        [(k, sorted(r)) for k, r in want]
+
+
+def test_table3_walkthrough_counts_picks_and_fills(monkeypatch):
+    """The device pack counts its greedy steps and fills.  Table 3 by hand:
+    on it1, τ1, τ2 and τ4 in 4 steps (3 picks, then nothing fits) and τ3
+    in a rejected fill of 2 steps (a pick, then no task left); on it2 one
+    step, nothing fits; on it3 τ3 in 2 steps; it4 has no task left to
+    fill.  9 steps in 4 fills, tagged on the ``jax_pack`` span and read
+    back only where a profiler is active."""
+    from repro.core import engine_jax
+    from repro.obs import profiler as prof
+    specs = [(2, 8, 24), (1, 4, 10), (0, 6, 20), (0, 4, 12)]
+    ts = TaskSet([Task(i, i, i, {"p3": tuple(map(float, s))})
+                  for i, s in enumerate(specs)])
+    cat = table3_catalog()
+    kw = dict(interference_aware=False, multi_task_aware=False,
+              engine="jax")
+    p = prof.Profiler()
+    prof.activate(p)
+    try:
+        cfg = full_reconfiguration(ts, cat, None, **kw)
+    finally:
+        prof.activate(None)
+    assert cfg.total_hourly_cost(cat) == pytest.approx(12.8)
+    (sp,) = p.by_name("jax_pack")
+    assert (sp.tags["picks"], sp.tags["fills"]) == (9, 4)
+
+    class Unread:
+        def __int__(self):
+            raise AssertionError("a count read back without a profiler")
+
+        __index__ = __bool__ = __int__
+
+    jitted = engine_jax._pack_all_types
+
+    def counts_unread(*a, **k):
+        return jitted(*a, **k)[:6] + (Unread(), Unread())
+
+    counts_unread._cache_size = jitted._cache_size
+    monkeypatch.setattr(engine_jax, "_pack_all_types", counts_unread)
+    assert full_reconfiguration(ts, cat, None, **kw).assignments == \
+        cfg.assignments

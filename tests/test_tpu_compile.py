@@ -9,6 +9,7 @@ width, and the planner's fused packing pass.  Nothing runs, so these say
 nothing about results or times.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -97,16 +98,86 @@ def test_rglru_compiles_for_v5e(one_chip, dtype, grad):
              _spec(one_chip, (2, 2560), jnp.float32))
 
 
+def _compiled_pack(cache, sharding, C, M, K, max_fills):
+    """``_pack_all_types`` compiled at a bucket of C task classes, M rows
+    per class and K catalog types (once per module and bucket)."""
+    key = (C, M, K, max_fills)
+    if key not in cache:
+        W, F, R = NUM_WORKLOADS, len(FAMILIES), NUM_RESOURCES
+        f32, i32 = np.float32, np.int32
+        args = [_spec(sharding, s, d) for s, d in (
+            ((F, R, C), f32), ((C,), i32), ((C,), f32), ((C,), f32),
+            ((C,), i32), ((C, M), i32), ((W, W), f32), ((W, W), f32),
+            ((K,), f32), ((K, R), f32), ((K,), i32), ((K,), i32),
+            ((4,), i32))]
+        cache[key] = _pack_all_types.lower(
+            *args, max_fills=max_fills).compile()
+    return cache[key]
+
+
+@pytest.fixture(scope="module")
+def pack_programs():
+    return {}
+
+
 @pytest.mark.parametrize("C,M,max_fills", [(16, 8192, 4096),
                                            (64, 1 << 17, 1 << 16)])
-def test_pack_all_types_compiles_for_v5e(one_chip, C, M, max_fills):
+def test_pack_all_types_compiles_for_v5e(one_chip, pack_programs, C, M,
+                                         max_fills):
     """The planner's fused pass at a fleet-sized bucket: C task classes,
     the 64-type catalog bucket, M rows per class."""
-    K, W, F, R = 64, NUM_WORKLOADS, len(FAMILIES), NUM_RESOURCES
-    f32, i32 = np.float32, np.int32
-    args = [_spec(one_chip, s, d) for s, d in (
-        ((C, F, R), f32), ((C,), i32), ((C,), f32), ((C,), f32),
-        ((C,), i32), ((C, M), i32), ((W, W), f32), ((W, W), f32),
-        ((K,), f32), ((K, R), f32), ((K,), i32), ((K,), i32), ((4,), i32))]
-    compiled = _pack_all_types.lower(*args, max_fills=max_fills).compile()
+    compiled = _compiled_pack(pack_programs, one_chip, C, M, 64, max_fills)
     assert compiled.memory_analysis() is not None
+
+
+def _computations(text):
+    """Name -> body of every computation in an HLO module's text."""
+    comps, name, lines = {}, None, []
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name, lines = m.group(1), []
+        elif line == "}" and name is not None:
+            comps[name] = "\n".join(lines)
+            name = None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def _innermost_loops(text):
+    """For each ``while`` whose body runs no other ``while``: the bodies of
+    its body computation and of every computation that one calls."""
+    comps = _computations(text)
+
+    def reach(name, seen):
+        if name not in seen:
+            seen.add(name)
+            for ref in re.findall(r"%([\w.\-]+)", comps[name]):
+                if ref in comps:
+                    reach(ref, seen)
+        return seen
+
+    loops = []
+    for body in re.findall(r"\bwhile\(.*?body=%([\w.\-]+)", text):
+        called = [comps[c] for c in reach(body, set())]
+        if not any(re.search(r"\bwhile\(", c) for c in called):
+            loops.append(called)
+    return loops
+
+
+@pytest.mark.parametrize("C,M,K,max_fills", [(1024, 8, 21, 512),
+                                             (16, 8192, 64, 4096),
+                                             (64, 1 << 17, 64, 1 << 16)],
+                         ids=["fleet1k-steady", "M8192", "M131072"])
+def test_pack_pick_loop_has_no_gather(one_chip, pack_programs, C, M, K,
+                                      max_fills):
+    """The greedy pick loop, the innermost ``while`` of the fused pass,
+    reads every per-class operand densely: no ``gather`` in its body nor
+    in any fusion it calls, whatever the class or row bucket."""
+    text = _compiled_pack(pack_programs, one_chip, C, M, K,
+                          max_fills).as_text()
+    loops = _innermost_loops(text)
+    assert loops
+    for called in loops:
+        assert not any(re.search(r"\bgather\(", c) for c in called)
